@@ -9,19 +9,11 @@ Run:  PYTHONPATH=src python examples/serve_decode.py
 
 import time
 
-from repro.compat import JAX_DRIFT_REASON, jax_api_drifted
+import jax
+import jax.numpy as jnp
 
-if jax_api_drifted():
-    # same detection tests/conftest.py uses — skip, don't crash, so the
-    # example stays CI-registered on containers with drifted jax
-    print(f"serve_decode: SKIP — {JAX_DRIFT_REASON}")
-    raise SystemExit(0)
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from repro.configs import get_smoke  # noqa: E402
-from repro.models import build_model  # noqa: E402
+from repro.configs import get_smoke
+from repro.models import build_model
 
 ARCH = "qwen3-14b"
 BATCH, PROMPT, GEN = 8, 48, 16

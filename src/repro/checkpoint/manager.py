@@ -48,6 +48,16 @@ def _path_str(p) -> str:
     return str(p)
 
 
+def _to_storable(arr: np.ndarray) -> np.ndarray:
+    """``np.save`` writes dtypes numpy does not know (bfloat16, float8) as
+    opaque ``|V`` records that cannot be loaded back as numbers. Store those
+    as unsigned ints of the same width; the manifest keeps the dtype name and
+    ``restore`` views the bytes back, bit for bit."""
+    if arr.dtype.kind == "V":
+        return arr.view(f"u{arr.dtype.itemsize}")
+    return arr
+
+
 class CheckpointManager:
     def __init__(
         self,
@@ -82,7 +92,7 @@ class CheckpointManager:
             arr = np.asarray(leaf)
             fname = key.replace("/", ".") + ".npy"
             buf = io.BytesIO()
-            np.save(buf, arr, allow_pickle=False)
+            np.save(buf, _to_storable(arr), allow_pickle=False)
             data = buf.getvalue()
             self.client.write_file(f"{d}/{fname}", data)
             manifest["leaves"].append(
@@ -127,7 +137,13 @@ class CheckpointManager:
                 out.append(int(name.split("-")[1]))
         return sorted(out)
 
-    def restore(self, tree_like: Any, step: Optional[int] = None) -> tuple[Any, int]:
+    def restore(self, tree_like: Any, step: Optional[int] = None, *,
+                shardings: Any = None) -> tuple[Any, int]:
+        """Read a committed step into ``tree_like``'s structure. Leaves of
+        ``tree_like`` may be arrays or ``jax.ShapeDtypeStruct``s: only their
+        paths are used. ``shardings``, a matching tree, places each leaf
+        straight onto its devices, so a sharded model never lands whole on
+        one device."""
         steps = self.steps()
         if not steps:
             raise FSError("no committed checkpoints")
@@ -138,12 +154,16 @@ class CheckpointManager:
         manifest = json.loads(self.client.read_file(f"{d}/manifest.json"))
         by_key = {m["key"]: m for m in manifest["leaves"]}
         leaves = _flatten_with_paths(tree_like)
+        shs = (jax.tree.leaves(shardings) if shardings is not None
+               else [None] * len(leaves))
         out = []
-        for key, like in leaves:
+        for (key, _), sh in zip(leaves, shs, strict=True):
             m = by_key[key]
             raw = self.client.read_file(f"{d}/{m['file']}")
             arr = np.load(io.BytesIO(raw), allow_pickle=False)
-            out.append(jax.numpy.asarray(arr))
+            arr = arr.view(jax.numpy.dtype(m["dtype"]))
+            out.append(jax.device_put(arr, sh) if sh is not None
+                       else jax.numpy.asarray(arr))
         restored = jax.tree.unflatten(jax.tree.structure(tree_like), out)
         return restored, step
 

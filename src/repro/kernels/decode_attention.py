@@ -16,6 +16,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -2.0e38
+BLOCK_K = 512       # default KV tile; a cache longer than this is a multiple of it
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -65,7 +66,7 @@ def decode_attention(
     *,
     kv_len,
     window: Optional[int] = None,
-    block_k: int = 512,
+    block_k: int = BLOCK_K,
     interpret: bool = True,
 ) -> jnp.ndarray:
     """q: (B,1,H,hd); k/v: (B,T,K,hd); kv_len: scalar int (# valid entries,

@@ -24,11 +24,19 @@ def make_production_mesh(*, multi_pod: bool = False):
             "(dry-runs must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before importing jax)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _auto_mesh(shape, axes, devices[:need])
 
 
-def make_smoke_mesh(data: int = 2, model: int = 2):
-    """Small mesh for CPU multi-device tests (subprocess-scoped XLA_FLAGS)."""
+def make_host_mesh(data: int = 2, model: int = 2):
+    """(data, model) mesh over the first ``data * model`` devices: CPU
+    multi-device tests (subprocess-scoped XLA_FLAGS) and one-host chip runs."""
     need = data * model
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:need])
+    return _auto_mesh((data, model), ("data", "model"), jax.devices()[:need])
+
+
+def _auto_mesh(shape, axes, devices):
+    # The step builders place activations with with_sharding_constraint and
+    # leave the rest to GSPMD propagation, which needs Auto axes (make_mesh
+    # defaults to Explicit).
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

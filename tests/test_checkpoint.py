@@ -98,3 +98,25 @@ def test_file_per_shard_layout(burst):
     files = burst.readdir(f"{mgr.root}/step-{1:08d}")
     npys = [f for f in files if f.endswith(".npy")]
     assert len(npys) == 4  # one per leaf
+
+
+def test_bf16_and_fp32_leaves_round_trip_bit_for_bit(burst):
+    """bfloat16 leaves (every full config's dtype) restore with their dtype
+    and exact bits, beside float32 and integer leaves."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(0))
+    tree = {
+        "params": {"w": jax.random.normal(k1, (16, 8), jnp.bfloat16),
+                   "scale": jnp.array([1.5, -0.0, jnp.inf], jnp.bfloat16)},
+        "opt": {"master": jax.random.normal(k2, (16, 8), jnp.float32),
+                "step": jnp.int32(7)},
+    }
+    mgr = CheckpointManager(burst)
+    man = mgr.save(3, tree)
+    assert {m["dtype"] for m in man["leaves"]} == {"bfloat16", "float32", "int32"}
+    like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    restored, step = mgr.restore(like)
+    assert step == 3
+    for a, b in zip(jax.tree.leaves(restored), jax.tree.leaves(tree)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        bits = f"u{a.dtype.itemsize}"
+        np.testing.assert_array_equal(np.asarray(a).view(bits), np.asarray(b).view(bits))

@@ -9,10 +9,6 @@ import textwrap
 
 import pytest
 
-from tests.conftest import JAX_DRIFT_REASON, jax_api_drifted
-
-pytestmark = pytest.mark.skipif(jax_api_drifted(), reason=JAX_DRIFT_REASON)
-
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -36,7 +32,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.configs import get_smoke
         from repro.models import build_model
         from repro.runtime import RuntimeConfig, make_train_state, jit_train_step, make_train_step
-        from repro.launch.mesh import make_smoke_mesh
+        from repro.launch.mesh import make_host_mesh
 
         cfg = get_smoke("phi4-mini-3.8b")
         model = build_model(cfg)
@@ -50,7 +46,7 @@ def test_sharded_train_step_matches_single_device():
         ref_step = jax.jit(make_train_step(model, rt))
         ref_state, ref_m = ref_step(state, batch)
 
-        mesh = make_smoke_mesh(4, 2)
+        mesh = make_host_mesh(4, 2)
         state2 = make_train_state(model, jax.random.PRNGKey(0), rt)
         step, st_sh, b_sh = jit_train_step(model, mesh, rt, state2, batch)
         state2 = jax.device_put(state2, st_sh)
@@ -68,7 +64,7 @@ def test_decode_step_sharded_cache():
         from repro.configs import get_smoke
         from repro.models import build_model
         from repro.runtime import RuntimeConfig, jit_decode_step
-        from repro.launch.mesh import make_smoke_mesh
+        from repro.launch.mesh import make_host_mesh
 
         cfg = get_smoke("qwen3-14b")
         model = build_model(cfg)
@@ -76,7 +72,7 @@ def test_decode_step_sharded_cache():
         params = model.init(jax.random.PRNGKey(0))
         cache = model.init_cache(8, 64)
         batch = {"token": jnp.ones((8,), jnp.int32)}
-        mesh = make_smoke_mesh(2, 4)
+        mesh = make_host_mesh(2, 4)
         step, p_sh, c_sh, b_sh = jit_decode_step(model, mesh, rt, params, cache, batch)
         params = jax.device_put(params, p_sh)
         cache = jax.device_put(cache, c_sh)
@@ -102,12 +98,12 @@ def test_dryrun_cell_small_mesh_moe():
         from repro.runtime import RuntimeConfig, make_train_state, jit_train_step
         from repro.runtime.costs import hlo_collective_bytes, jaxpr_costs
         from repro.runtime.parallel import make_train_step
-        from repro.launch.mesh import make_smoke_mesh
+        from repro.launch.mesh import make_host_mesh
 
         cfg = get_smoke("qwen3-moe-30b-a3b")
         model = build_model(cfg)
         rt = RuntimeConfig(accum=2)
-        mesh = make_smoke_mesh(2, 4)
+        mesh = make_host_mesh(2, 4)
         rng_sds = jax.ShapeDtypeStruct((2,), jnp.uint32)
         state_sds = jax.eval_shape(lambda r: make_train_state(model, r, rt), rng_sds)
         specs = {

@@ -5,11 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests.conftest import JAX_DRIFT_REASON, jax_api_drifted
-
-pytestmark = pytest.mark.skipif(jax_api_drifted(), reason=JAX_DRIFT_REASON)
-
-from repro.kernels import ops, ref  # noqa: E402
+from repro.kernels import ops, ref
 
 jax.config.update("jax_enable_x64", False)
 

@@ -9,14 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests.conftest import JAX_DRIFT_REASON, jax_api_drifted
-
-pytestmark = pytest.mark.skipif(jax_api_drifted(), reason=JAX_DRIFT_REASON)
-
-from repro.configs import ARCH_IDS, get_config, get_smoke, shapes_for  # noqa: E402
-from repro.models import build_model  # noqa: E402
-from repro.models.attention import blockwise_sdpa, sdpa  # noqa: E402
-from repro.runtime import (  # noqa: E402
+from repro.configs import ARCH_IDS, get_config, get_smoke, shapes_for
+from repro.models import build_model
+from repro.models.attention import blockwise_sdpa, sdpa
+from repro.runtime import (
     RuntimeConfig,
     make_train_state,
     make_train_step,
