@@ -1,0 +1,15 @@
+"""Decode's share of the chip's peak FLOP/s: each step's operations (the
+valid cache only), counted from shapes, over the decode spans."""
+
+from harness import costs
+
+
+def read(run):
+    spans = run.spans.get("decode_step")
+    if not spans:
+        return None
+    d, B, P = run.dims, run.data["B"], run.data["P"]
+    flops = sum(costs.decode_flops(d, B, P + j - 1, run.data["S_max"])
+                for G in run.data["wave_steps"] for j in range(1, G))
+    t = sum(b - a for a, b in spans)
+    return 100.0 * flops / t / run.peaks["bf16_flops_per_s"]
