@@ -90,6 +90,7 @@ def decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid=(B * H, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

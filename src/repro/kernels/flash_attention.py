@@ -99,6 +99,7 @@ def flash_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B * H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, BQ, hd), lambda bh, iq, ik: (bh, iq, 0)),

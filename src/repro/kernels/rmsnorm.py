@@ -34,6 +34,7 @@ def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, *, eps: float = 1e-5,
     kernel = functools.partial(_kernel, eps=eps)
     out = pl.pallas_call(
         kernel,
+        name="rmsnorm",
         grid=(rows // BR,),
         in_specs=[
             pl.BlockSpec((BR, d), lambda i: (i, 0)),

@@ -77,6 +77,7 @@ def ssd_intra_chunk(la, C, B_in, x, *, interpret: bool = True):
     kernel = functools.partial(_kernel, Q=Q)
     y, st = pl.pallas_call(
         kernel,
+        name="ssd_scan",
         grid=(n, H),
         in_specs=[
             pl.BlockSpec((1, 1, 1, Q), lambda i, h: (i, h, 0, 0)),

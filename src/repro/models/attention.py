@@ -200,68 +200,74 @@ def attention(
 
     B, S, d = x.shape
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    # head-aligned layout: shard heads over "model" when divisible, else
-    # replicate — never let GSPMD split hd (see hints.py docstring)
-    q = constrain(dense(p["wq"], x).reshape(B, S, H, hd), "dp", None, "model", None)
-    if kv_override is None:
-        k = constrain(dense(p["wk"], x).reshape(B, S, K, hd), "dp", None, "model", None)
-        v = constrain(dense(p["wv"], x).reshape(B, S, K, hd), "dp", None, "model", None)
-    else:
-        k, v = kv_override
-
-    if cfg.qk_norm:
-        q = head_rmsnorm(p["q_norm"], q, cfg.norm_eps)
+    with jax.named_scope("attn_proj"):
+        # head-aligned layout: shard heads over "model" when divisible, else
+        # replicate — never let GSPMD split hd (see hints.py docstring)
+        q = constrain(dense(p["wq"], x).reshape(B, S, H, hd), "dp", None, "model", None)
         if kv_override is None:
-            k = head_rmsnorm(p["k_norm"], k, cfg.norm_eps)
+            k = constrain(dense(p["wk"], x).reshape(B, S, K, hd), "dp", None, "model", None)
+            v = constrain(dense(p["wv"], x).reshape(B, S, K, hd), "dp", None, "model", None)
+        else:
+            k, v = kv_override
 
-    if kv_override is None and theta > 0:
-        cos, sin = rope_tables(positions, hd, theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cfg.qk_norm:
+            q = head_rmsnorm(p["q_norm"], q, cfg.norm_eps)
+            if kv_override is None:
+                k = head_rmsnorm(p["k_norm"], k, cfg.norm_eps)
+
+        if kv_override is None and theta > 0:
+            cos, sin = rope_tables(positions, hd, theta)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
     if kv_override is not None:
-        out = sdpa(q, k, v, causal=False)
+        with jax.named_scope("attend"):
+            out = sdpa(q, k, v, causal=False)
         new_cache = None
     elif cache is None:
-        if use_kernels:
-            from ..kernels import ops as kops
-            out = kops.flash_attention(q, k, v, causal=causal, window=window)
-        elif S >= BLOCKWISE_THRESHOLD:
-            out = blockwise_sdpa(q, k, v, causal=causal, window=window)
-        else:
-            out = sdpa(q, k, v, causal=causal, window=window)
+        with jax.named_scope("attend"):
+            if use_kernels:
+                from ..kernels import ops as kops
+                out = kops.flash_attention(q, k, v, causal=causal, window=window)
+            elif S >= BLOCKWISE_THRESHOLD:
+                out = blockwise_sdpa(q, k, v, causal=causal, window=window)
+            else:
+                out = sdpa(q, k, v, causal=causal, window=window)
         new_cache = KVCache(k, v)
     else:
         # decode: write k/v at cache_write_pos (ring caches pass pos % W),
         # attend over the valid region.
         wp = cache_pos if cache_write_pos is None else cache_write_pos
-        ck = jax.lax.dynamic_update_slice(cache.k, k, (0, wp, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache.v, v, (0, wp, 0, 0))
-        if kv_positions is not None:
-            # ring cache: validity comes from the positions array
-            out = sdpa(
-                q, ck, cv,
-                causal=True,
-                window=window,
-                q_offset=cache_pos,
-                kv_positions=kv_positions,
-            )
-        elif use_kernels:
-            from ..kernels import ops as kops
-            out = kops.decode_attention(
-                q, ck, cv, kv_len=cache_pos + S, window=window
-            )
-        else:
-            out = sdpa(
-                q, ck, cv,
-                causal=True,
-                window=window,
-                q_offset=cache_pos,
-                kv_len=cache_pos + S,
-            )
+        with jax.named_scope("kv_write"):
+            ck = jax.lax.dynamic_update_slice(cache.k, k, (0, wp, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cache.v, v, (0, wp, 0, 0))
+        with jax.named_scope("attend"):
+            if kv_positions is not None:
+                # ring cache: validity comes from the positions array
+                out = sdpa(
+                    q, ck, cv,
+                    causal=True,
+                    window=window,
+                    q_offset=cache_pos,
+                    kv_positions=kv_positions,
+                )
+            elif use_kernels:
+                from ..kernels import ops as kops
+                out = kops.decode_attention(
+                    q, ck, cv, kv_len=cache_pos + S, window=window
+                )
+            else:
+                out = sdpa(
+                    q, ck, cv,
+                    causal=True,
+                    window=window,
+                    q_offset=cache_pos,
+                    kv_len=cache_pos + S,
+                )
         new_cache = KVCache(ck, cv)
 
-    y = dense(p["wo"], out.reshape(B, S, H * hd))
+    with jax.named_scope("attn_proj"):
+        y = dense(p["wo"], out.reshape(B, S, H * hd))
     return y, new_cache
 
 

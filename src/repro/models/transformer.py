@@ -3,6 +3,12 @@ local/global block pattern, and VLM (prefix patch embeddings).
 
 Layer stacks are scanned (``lax.scan``) over stacked params for compile-time
 O(1) in depth; gemma3 uses a nested scan over (blocks x [R local + 1 global]).
+
+Train, prefill and decode name their work with ``jax.named_scope`` from one
+vocabulary, which profiler traces carry as each op's ``op_name`` path:
+``embed``, ``layers`` (the layer scan), ``norm``, ``attn_proj``, ``kv_write``,
+``attend``, ``mlp`` or ``moe``, and ``lm_head``. Scopes are HLO metadata only;
+the compiled program is the same without them.
 """
 
 from __future__ import annotations
@@ -65,9 +71,11 @@ def _layer_apply(
     use_kernels: bool = False,
 ):
     """Pre-norm block. Returns (x, new_kv, aux)."""
+    with jax.named_scope("norm"):
+        xn = rmsnorm(lp["ln1"], x, cfg.norm_eps)
     h, kv = attention(
         lp["attn"],
-        rmsnorm(lp["ln1"], x, cfg.norm_eps),
+        xn,
         cfg,
         positions=positions,
         theta=theta,
@@ -79,11 +87,14 @@ def _layer_apply(
         use_kernels=use_kernels,
     )
     x = x + h
-    y = rmsnorm(lp["ln2"], x, cfg.norm_eps)
+    with jax.named_scope("norm"):
+        y = rmsnorm(lp["ln2"], x, cfg.norm_eps)
     if cfg.family == "moe":
-        m, aux = moe_ffn(lp["moe"], y, cfg)
+        with jax.named_scope("moe"):
+            m, aux = moe_ffn(lp["moe"], y, cfg)
     else:
-        m, aux = swiglu(lp["mlp"], y), 0.0
+        with jax.named_scope("mlp"):
+            m, aux = swiglu(lp["mlp"], y), 0.0
     return x + m, kv, aux
 
 
@@ -159,9 +170,10 @@ def _forward(
             x, gkv, _ = global_fn(gp, x)
             return x, (lkv, gkv)
 
-        x, (lkvs, gkvs) = jax.lax.scan(
-            block, x, (params["local_layers"], params["global_layers"])
-        )
+        with jax.named_scope("layers"):
+            x, (lkvs, gkvs) = jax.lax.scan(
+                block, x, (params["local_layers"], params["global_layers"])
+            )
         cache = {"local": lkvs, "global": gkvs} if want_cache else None
         return x, cache, 0.0
 
@@ -178,22 +190,30 @@ def _forward(
         x, kv, a = layer_fn(lp, x)
         return (x, aux + a), kv
 
-    (x, aux), kvs = jax.lax.scan(body, (x, 0.0), params["layers"])
+    with jax.named_scope("layers"):
+        (x, aux), kvs = jax.lax.scan(body, (x, 0.0), params["layers"])
     return x, (kvs if want_cache else None), aux
 
 
 def _embed_inputs(params, cfg: ModelConfig, batch):
     """Token embedding (+ VLM patch-prefix concat). Returns (x, n_prefix)."""
-    x = embed(params["embed"], batch["tokens"])
-    if cfg.d_model and cfg.family == "vlm" and "patch_embeds" in batch:
-        x = jnp.concatenate([batch["patch_embeds"].astype(x.dtype), x], axis=1)
-        return x, batch["patch_embeds"].shape[1]
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], batch["tokens"])
+        if cfg.d_model and cfg.family == "vlm" and "patch_embeds" in batch:
+            x = jnp.concatenate([batch["patch_embeds"].astype(x.dtype), x], axis=1)
+            return x, batch["patch_embeds"].shape[1]
     return x, 0
 
 
 def _logits(params, cfg: ModelConfig, h):
     p = params.get("unembed", params["embed"])
-    return unembed(p, h)
+    with jax.named_scope("lm_head"):
+        return unembed(p, h)
+
+
+def _final_norm(params, cfg: ModelConfig, h):
+    with jax.named_scope("norm"):
+        return rmsnorm(params["final_norm"], h, cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------
@@ -207,7 +227,7 @@ def loss_fn(params, batch, cfg: ModelConfig, *, remat=None, use_kernels=False):
         params, cfg, x, positions, want_cache=False, remat=remat,
         use_kernels=use_kernels,
     )
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    h = _final_norm(params, cfg, h)
     if n_prefix:
         h = h[:, n_prefix:]
     logits = _logits(params, cfg, h)
@@ -225,7 +245,7 @@ def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
     h, kvs, _ = _forward(
         params, cfg, x, positions, want_cache=True, use_kernels=use_kernels
     )
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    h = _final_norm(params, cfg, h)
     logits = _logits(params, cfg, h[:, -1])
 
     if cfg.local_global_ratio:
@@ -264,7 +284,8 @@ def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
 def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
     """One token for every sequence. batch: {"token": (B,)}."""
     tok = batch["token"]
-    x = embed(params["embed"], tok[:, None])
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], tok[:, None])
     pos = cache["pos"]
     positions = pos[None]
 
@@ -294,11 +315,12 @@ def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
             )
             return x, (lkv, gkv)
 
-        x, (lkvs, gkvs) = jax.lax.scan(
-            block, x,
-            (params["local_layers"], cache["lk"], cache["lv"],
-             params["global_layers"], cache["gk"], cache["gv"]),
-        )
+        with jax.named_scope("layers"):
+            x, (lkvs, gkvs) = jax.lax.scan(
+                block, x,
+                (params["local_layers"], cache["lk"], cache["lv"],
+                 params["global_layers"], cache["gk"], cache["gv"]),
+            )
         new_cache = {
             "lk": lkvs.k, "lv": lkvs.v, "ring_pos": ring_pos,
             "gk": gkvs.k, "gv": gkvs.v, "pos": pos + 1,
@@ -314,12 +336,13 @@ def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
             )
             return (x, a), kv
 
-        (x, _), kvs = jax.lax.scan(
-            body, (x, 0.0), (params["layers"], cache["k"], cache["v"])
-        )
+        with jax.named_scope("layers"):
+            (x, _), kvs = jax.lax.scan(
+                body, (x, 0.0), (params["layers"], cache["k"], cache["v"])
+            )
         new_cache = {"k": kvs.k, "v": kvs.v, "pos": pos + 1}
 
-    h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    h = _final_norm(params, cfg, x)
     logits = _logits(params, cfg, h[:, 0])
     return logits, new_cache
 

@@ -133,12 +133,12 @@ def jit_train_step(
     metric_sh = NamedSharding(mesh, P())
     raw_step = make_train_step(model, rt)
 
-    def hinted(state, batch):
+    def train_step(state, batch):               # module jit_train_step
         with mesh_hint(mesh, rt.flags):
             return raw_step(state, batch)
 
     step = jax.jit(
-        hinted,
+        train_step,
         in_shardings=(st_sh, b_sh),
         out_shardings=(st_sh, None),
         donate_argnums=(0,) if rt.donate else (),
@@ -179,12 +179,12 @@ def jit_decode_step(
     b_sh = sh.batch_shardings(mesh, batch_like)
     raw_step = make_decode_step(model, rt)
 
-    def hinted(params, cache, batch):
+    def decode_step(params, cache, batch):      # module jit_decode_step
         with mesh_hint(mesh, rt.flags):
             return raw_step(params, cache, batch)
 
     step = jax.jit(
-        hinted,
+        decode_step,
         in_shardings=(p_sh, c_sh, b_sh),
         out_shardings=(None, c_sh),
         donate_argnums=(1,) if rt.donate else (),
@@ -206,12 +206,12 @@ def jit_prefill(
     c_sh = sh.cache_shardings(mesh, cache_like, model.cfg)
     raw_step = make_prefill(model, S_max, rt)
 
-    def hinted(params, batch):
+    def prefill(params, batch):                 # module jit_prefill
         with mesh_hint(mesh, rt.flags):
             return raw_step(params, batch)
 
     step = jax.jit(
-        hinted,
+        prefill,
         in_shardings=(p_sh, b_sh),
         out_shardings=(None, c_sh),
     )
