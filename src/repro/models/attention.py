@@ -19,7 +19,10 @@ _NEG = -2.0e38
 
 
 class KVCache(NamedTuple):
-    """Per-layer decode cache. k/v: (B, S_max, K, hd); pos: scalar int32."""
+    """Decode cache keys and values. Per layer, k/v: (B, S_max, K, hd). The
+    transformer's uniform layer stack keeps all layers in one pair of
+    (L, S_max, K, B, hd) stacks instead, the layout its decode loop reads in
+    place (``attention(..., layer=...)``)."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -185,6 +188,7 @@ def attention(
     cache_write_pos=None,
     kv_positions=None,
     kv_override: Optional[tuple] = None,
+    layer=None,
     use_kernels: bool = False,
 ):
     """Full attention sub-layer: qkv proj -> rope -> sdpa -> out proj.
@@ -194,6 +198,10 @@ def attention(
         (out, KVCache(k, v)) so prefill can keep the cache.
       * decode: ``cache`` given, x is (B, 1, d); keys/values are inserted at
         ``cache_pos`` and attention runs over the cache prefix.
+        With ``layer`` (a traced index), ``cache`` holds every layer's
+        (L, S_max, K, B, hd) stacks: only the new token's row of that layer
+        is written, the layer's slice is read in place, and the stacks are
+        returned.
       * cross-attention: ``kv_override=(k, v)`` skips rope/cache.
     """
     from ..hints import constrain
@@ -239,13 +247,29 @@ def attention(
         # attend over the valid region.
         wp = cache_pos if cache_write_pos is None else cache_write_pos
         with jax.named_scope("kv_write"):
-            ck = jax.lax.dynamic_update_slice(cache.k, k, (0, wp, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache.v, v, (0, wp, 0, 0))
+            if layer is None:
+                ck = jax.lax.dynamic_update_slice(cache.k, k, (0, wp, 0, 0))
+                cv = jax.lax.dynamic_update_slice(cache.v, v, (0, wp, 0, 0))
+            else:
+                # one (1, K, B, hd) row of the stacks changes; they stay in
+                # place through the layer scan
+                at = (layer, wp, 0, 0, 0)
+                ck = jax.lax.dynamic_update_slice(cache.k, seq_major(k)[None], at)
+                cv = jax.lax.dynamic_update_slice(cache.v, seq_major(v)[None], at)
         with jax.named_scope("attend"):
+            if layer is None:
+                kc, vc = ck, cv
+            else:
+                # the layer's slice as (B, S_max, K, hd); the compiler folds
+                # the transpose into the attention's reads (re-laying the
+                # slice out as (B*K, S_max, hd) matrices first is slower)
+                kc = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
+                vc = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
+                kc, vc = kc.transpose(2, 0, 1, 3), vc.transpose(2, 0, 1, 3)
             if kv_positions is not None:
                 # ring cache: validity comes from the positions array
                 out = sdpa(
-                    q, ck, cv,
+                    q, kc, vc,
                     causal=True,
                     window=window,
                     q_offset=cache_pos,
@@ -254,11 +278,11 @@ def attention(
             elif use_kernels:
                 from ..kernels import ops as kops
                 out = kops.decode_attention(
-                    q, ck, cv, kv_len=cache_pos + S, window=window
+                    q, kc, vc, kv_len=cache_pos + S, window=window
                 )
             else:
                 out = sdpa(
-                    q, ck, cv,
+                    q, kc, vc,
                     causal=True,
                     window=window,
                     q_offset=cache_pos,
@@ -269,6 +293,11 @@ def attention(
     with jax.named_scope("attn_proj"):
         y = dense(p["wo"], out.reshape(B, S, H * hd))
     return y, new_cache
+
+
+def seq_major(a):
+    """(B, S, K, hd) -> (S, K, B, hd), the order of the layer stacks."""
+    return a.transpose(1, 2, 0, 3)
 
 
 def empty_cache(cfg: ModelConfig, B: int, S_max: int, dtype) -> KVCache:

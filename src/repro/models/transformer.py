@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, ShapeSpec
-from .attention import KVCache, attention, attn_init
+from .attention import KVCache, attention, attn_init, seq_major
 from .common import Model, remat_wrap, stack_init, token_specs
 from .layers import (
     cross_entropy_loss,
@@ -68,6 +68,7 @@ def _layer_apply(
     cache_pos=None,
     cache_write_pos=None,
     kv_positions=None,
+    layer=None,
     use_kernels: bool = False,
 ):
     """Pre-norm block. Returns (x, new_kv, aux)."""
@@ -84,6 +85,7 @@ def _layer_apply(
         cache_pos=cache_pos,
         cache_write_pos=cache_write_pos,
         kv_positions=kv_positions,
+        layer=layer,
         use_kernels=use_kernels,
     )
     x = x + h
@@ -188,11 +190,13 @@ def _forward(
     def body(carry, lp):
         x, aux = carry
         x, kv, a = layer_fn(lp, x)
+        # the cache is stacked in the decode loop's (S, K, B, hd) order
+        kv = KVCache(seq_major(kv.k), seq_major(kv.v)) if want_cache else None
         return (x, aux + a), kv
 
     with jax.named_scope("layers"):
         (x, aux), kvs = jax.lax.scan(body, (x, 0.0), params["layers"])
-    return x, (kvs if want_cache else None), aux
+    return x, kvs, aux
 
 
 def _embed_inputs(params, cfg: ModelConfig, batch):
@@ -273,9 +277,9 @@ def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
             "pos": jnp.int32(S),
         }
     else:
-        def grow(a):
+        def grow(a):                            # (L, S, K, B, hd)
             pad = [(0, 0)] * a.ndim
-            pad[-3] = (0, S_max - S)
+            pad[1] = (0, S_max - S)
             return jnp.pad(a, pad)
         cache = {"k": grow(kvs.k), "v": grow(kvs.v), "pos": jnp.int32(S)}
     return logits, cache
@@ -326,21 +330,24 @@ def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
             "gk": gkvs.k, "gv": gkvs.v, "pos": pos + 1,
         }
     else:
+        # the cache stacks ride in the carry and are updated in place; a scan
+        # over them as xs/ys would slice and restack them whole every step
         def body(carry, inp):
-            x, _ = carry
-            lp, k1, v1 = inp
+            x, _, ks, vs = carry
+            lp, layer = inp
             x, kv, a = _layer_apply(
                 lp, x, cfg, positions=positions, theta=cfg.rope_theta,
-                window=cfg.sliding_window, cache=KVCache(k1, v1),
-                cache_pos=pos, use_kernels=use_kernels,
+                window=cfg.sliding_window, cache=KVCache(ks, vs),
+                cache_pos=pos, layer=layer, use_kernels=use_kernels,
             )
-            return (x, a), kv
+            return (x, a, kv.k, kv.v), None
 
         with jax.named_scope("layers"):
-            (x, _), kvs = jax.lax.scan(
-                body, (x, 0.0), (params["layers"], cache["k"], cache["v"])
+            (x, _, k, v), _ = jax.lax.scan(
+                body, (x, 0.0, cache["k"], cache["v"]),
+                (params["layers"], jnp.arange(cfg.n_layers)),
             )
-        new_cache = {"k": kvs.k, "v": kvs.v, "pos": pos + 1}
+        new_cache = {"k": k, "v": v, "pos": pos + 1}
 
     h = _final_norm(params, cfg, x)
     logits = _logits(params, cfg, h[:, 0])
@@ -364,8 +371,8 @@ def init_cache(cfg: ModelConfig, B: int, S_max: int):
         }
     L = cfg.n_layers
     return {
-        "k": jnp.zeros((L, B, S_max, K, hd), dtype),
-        "v": jnp.zeros((L, B, S_max, K, hd), dtype),
+        "k": jnp.zeros((L, S_max, K, B, hd), dtype),
+        "v": jnp.zeros((L, S_max, K, B, hd), dtype),
         "pos": jnp.int32(0),
     }
 

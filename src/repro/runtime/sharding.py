@@ -198,6 +198,8 @@ def cache_shardings(mesh: Mesh, cache_like, cfg):
     (flash-decode style context parallelism — head-count agnostic); SSM/mLSTM
     states shard heads or channels over "model"."""
     dp = dp_axes(mesh)
+    # the transformer's uniform layer stack: k/v are (L, S, K, B, hd)
+    uniform_stack = cfg.family in ("dense", "moe", "vlm") and not cfg.local_global_ratio
 
     def one(path, leaf):
         name = _path_str(path)
@@ -205,6 +207,10 @@ def cache_shardings(mesh: Mesh, cache_like, cfg):
         spec: list = [None] * len(shape)
         if leaf.ndim == 0:
             return NamedSharding(mesh, P())
+        if uniform_stack and name in ("k", "v"):
+            spec[-2] = dp
+            spec[-4] = MODEL
+            return NamedSharding(mesh, _sanitize(mesh, spec, shape))
         # mlstm matrix memory (..., B, H, dh, dh): BATCH-LOCAL (dp only).
         # Any model-axis sharding here loses: GSPMD cannot reshard between
         # the layouts the decode einsums prefer and replicates the whole

@@ -1,25 +1,32 @@
-"""Every Pallas kernel compiles natively for a TPU v5e at published widths.
+"""Every Pallas kernel compiles natively for a TPU v5e at published widths,
+and the decode step keeps its KV cache in place.
 
 Nothing runs: the TPU compiler, which is installed with jaxlib, compiles for
 a described v5e chip that is not attached. That catches what interpret mode
-cannot, such as blocks that break the chip's (8, 128) tiling. Each test
-asserts that the kernel reached the compiled program as a Mosaic custom call.
+cannot, such as blocks that break the chip's (8, 128) tiling. Each kernel
+test asserts that the kernel reached the compiled program as a Mosaic custom
+call.
 """
 
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssd_scan as _ssd
+from repro.models import build_model
+from repro.runtime import RuntimeConfig, jit_decode_step
 
 PHI4 = get_config("phi4-mini-3.8b")
 ZAMBA2 = get_config("zamba2-7b")
@@ -80,3 +87,27 @@ def test_ssd_intra_chunk_compiles_zamba2(one_chip):
     text = _compiled_text(fn, one_chip, ((B, nc, Q, H), f32), ((B, nc, Q, N), BF16),
                           ((B, nc, Q, N), BF16), ((B, nc, Q, H, P), BF16))
     assert "tpu_custom_call" in text
+
+
+def test_decode_step_keeps_cache_in_place(one_chip):
+    """phi4-mini widths, 2 layers, B=8, a 256-slot cache: the decode step
+    needs no temporary as large as one layer's K slice, and copies no whole
+    cache stack."""
+    cfg = dataclasses.replace(PHI4, n_layers=2)
+    model = build_model(cfg)
+    B, S_max = 8, 256
+    params_like = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache_like = jax.eval_shape(lambda: model.init_cache(B, S_max))
+    tok_like = {"token": jax.ShapeDtypeStruct((B,), jnp.int32)}
+    mesh = Mesh(np.array([*one_chip.device_set]).reshape(1, 1), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    step, *_ = jit_decode_step(model, mesh, RuntimeConfig(), params_like,
+                               cache_like, tok_like)
+    compiled = step.lower(params_like, cache_like, tok_like).compile()
+    layer_k_bytes = S_max * cfg.n_kv_heads * B * cfg.hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k_bytes
+    stack = "bf16[" + ",".join(map(str, cache_like["k"].shape)) + "]"
+    # copy ops and copy fusions of a whole stack: a scan over the stacks as
+    # xs and ys makes four a step, restacking the ys and copying them out
+    copies = re.findall(r"%(copy[\w.\-]*) = " + re.escape(stack), compiled.as_text())
+    assert not copies, copies

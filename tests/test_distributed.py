@@ -87,6 +87,33 @@ def test_decode_step_sharded_cache():
     """)
 
 
+def test_cache_shardings_follow_the_stack_layout():
+    """The uniform stack's (L, S, K, B, hd) cache shards batch over data and
+    sequence over model; gemma3's (..., B, S, K, hd) caches keep theirs."""
+    _run("""
+        import jax
+        from jax.sharding import PartitionSpec as P
+        from repro.configs import get_smoke
+        from repro.models import build_model
+        from repro.runtime import cache_shardings
+        from repro.launch.mesh import make_host_mesh
+
+        mesh = make_host_mesh(2, 4)
+        for arch, want in [
+            ("qwen3-14b", {"k": P(None, "model", None, "data", None)}),
+            ("internvl2-2b", {"v": P(None, "model", None, "data", None)}),
+            ("gemma3-12b", {"gk": P(None, "data", "model", None, None),
+                            "lk": P(None, None, "data", "model", None, None)}),
+        ]:
+            model = build_model(get_smoke(arch))
+            cache = jax.eval_shape(lambda: model.init_cache(8, 64))
+            sh = cache_shardings(mesh, cache, model.cfg)
+            for name, spec in want.items():
+                assert sh[name].spec == spec, (arch, name, sh[name].spec)
+        print("OK")
+    """)
+
+
 def test_dryrun_cell_small_mesh_moe():
     """MoE lowering + compile + roofline extraction on a small mesh —
     the dry-run machinery itself, in miniature."""

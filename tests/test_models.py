@@ -85,6 +85,60 @@ def test_decode_matches_full_forward(arch):
     assert err / scale < 2e-2, (arch, err, scale)
 
 
+# one (L, S_max, K, B, hd) stack per k and v, carried through the decode scan
+UNIFORM_STACK = [a for a in ARCH_IDS
+                 if get_smoke(a).family in ("dense", "moe", "vlm")
+                 and not get_smoke(a).local_global_ratio]
+
+
+@pytest.mark.parametrize("arch", UNIFORM_STACK)
+def test_decode_cache_matches_prefill(arch):
+    """Four decode steps write the rows a prefill of the whole sequence
+    writes, in the stacks' layout, and leave the rows after them zero."""
+    cfg = get_smoke(arch)
+    if cfg.family == "moe":
+        cfg = dataclasses.replace(cfg, moe_capacity_factor=8.0)  # no drops
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    n_pref = cfg.n_patches if cfg.family == "vlm" else 0
+    S_max = S + 8 + n_pref
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, S + 4), 0, cfg.vocab_size)
+    batch = dict(_batch(cfg, with_labels=False), tokens=toks[:, :S])
+    _, cache = model.prefill(params, batch, S_max)
+    for t in range(4):
+        _, cache = model.decode_step(params, cache, {"token": toks[:, S + t]})
+    _, full = model.prefill(params, dict(batch, tokens=toks), S_max)
+    n = n_pref + S + 4
+    assert int(cache["pos"]) == int(full["pos"]) == n
+    for name in ("k", "v"):
+        got, want = np.asarray(cache[name]), np.asarray(full[name])
+        assert got.shape == (cfg.n_layers, S_max, cfg.n_kv_heads, B, cfg.hd)
+        assert got.shape == model.init_cache(B, S_max)[name].shape
+        err = np.max(np.abs(got[:, :n] - want[:, :n]))
+        assert err / np.max(np.abs(want[:, :n])) < 2e-2, (arch, name, err)
+        assert not np.any(got[:, n:]), (arch, name)
+
+
+def test_decode_kernel_path_matches_jnp():
+    """The Pallas decode kernel (interpreted here) reads the layer's slice of
+    the stacks as the jnp attention does."""
+    cfg = get_smoke("phi4-mini-3.8b")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    S_max = S + 32
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, S + 4), 0, cfg.vocab_size)
+    _, cache = model.prefill(params, _batch(cfg, with_labels=False) | {"tokens": toks[:, :S]}, S_max)
+    ref = ker = cache
+    for t in range(4):
+        tok = {"token": toks[:, S + t]}
+        l_ref, ref = model.decode_step(params, ref, tok)
+        l_ker, ker = model.decode_step(params, ker, tok, use_kernels=True)
+        scale = float(jnp.max(jnp.abs(l_ref)))
+        err = float(jnp.max(jnp.abs(l_ker - l_ref)))
+        assert err / scale < 1e-4, (t, err, scale)
+    np.testing.assert_allclose(np.asarray(ker["k"]), np.asarray(ref["k"]), rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "zamba2-7b", "gemma3-12b"])
 def test_kernel_path_matches_reference(arch):
     cfg = get_smoke(arch)
