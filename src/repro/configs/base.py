@@ -46,6 +46,16 @@ class ModelConfig:
     ssm_conv_width: int = 4
     ssm_chunk: int = 128
     shared_attn_every: int = 0                  # zamba2: shared attn block period
+    # granite-4.0-h: each layer's mixer, "mamba" or "attention", each followed
+    # by an MLP; empty for the zamba2 shape
+    layer_types: tuple = ()
+
+    # granite multipliers (1.0 / None: none)
+    embedding_multiplier: float = 1.0           # embeddings x this
+    residual_multiplier: float = 1.0            # each sublayer's output x this
+    attention_multiplier: Optional[float] = None  # softmax scale; None: 1/sqrt(hd)
+    logits_scaling: float = 1.0                 # logits / this
+    nope: bool = False                          # no position embedding (NoPE)
 
     # xLSTM
     slstm_period: int = 0                       # 1 sLSTM per this many layers
@@ -67,6 +77,8 @@ class ModelConfig:
             raise ValueError("n_heads must be a multiple of n_kv_heads")
         if self.family == "moe" and (self.n_experts <= 0 or self.experts_per_token <= 0):
             raise ValueError("moe family needs n_experts/experts_per_token")
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError("layer_types must name every layer")
 
     @property
     def hd(self) -> int:
@@ -99,13 +111,18 @@ class ModelConfig:
             moe_mlp = self.n_experts * 3 * d * self.d_ff + d * self.n_experts
             n += self.n_layers * (attn + moe_mlp + 2 * d)
         elif self.family == "hybrid":
-            di, N, H = self.d_inner_ssm, self.ssm_state, self.n_ssm_heads
-            # in_proj -> [z, x, B, C, dt]; conv over (x,B,C); out_proj
-            conv_dim = di + 2 * N * 0 + 2 * self.ssm_state * H // H  # see mamba2.py
-            mamba = d * (2 * di + 2 * self.ssm_state + H) + di * d + 4 * di
-            n += self.n_layers * (mamba + 2 * d)
-            n_shared = (attn + dense_mlp + 2 * d) if self.shared_attn_every else 0
-            n += n_shared  # weight-tied: counted once
+            di, N, H, W = self.d_inner_ssm, self.ssm_state, self.n_ssm_heads, self.ssm_conv_width
+            conv_ch = di + 2 * N
+            # in_proj -> [z, x, B, C, dt]; depthwise conv (+ bias) over (x, B, C);
+            # A_log, dt_bias, D per head; gated norm; out_proj (mamba2.py)
+            mamba = d * (2 * di + 2 * N + H) + (W + 1) * conv_ch + 3 * H + di + di * d
+            if self.layer_types:
+                n_attn = self.layer_types.count("attention")
+                n += (self.n_layers - n_attn) * mamba + n_attn * attn
+                n += self.n_layers * (dense_mlp + 2 * d)
+            else:
+                n += self.n_layers * (mamba + d)
+                n += attn + dense_mlp + 2 * d  # the shared block, weight-tied: counted once
         elif self.family == "ssm":  # xlstm
             di = self.ssm_expand * d
             mlstm = d * (3 * di + di) + di * d + 3 * di
@@ -171,6 +188,12 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         base.update(ssm_state=16, ssm_head_dim=32, ssm_chunk=32)
     if cfg.shared_attn_every:
         base.update(n_layers=4, shared_attn_every=2)
+    if cfg.layer_types:
+        # one whole period of the pattern (through the first attention layer
+        # and up to the next)
+        i = cfg.layer_types.index("attention")
+        period = cfg.layer_types.index("attention", i + 1) - i
+        base.update(n_layers=period, layer_types=cfg.layer_types[:period])
     if cfg.slstm_period:
         base.update(n_layers=4, slstm_period=2)
     if cfg.encoder_layers:

@@ -17,6 +17,7 @@ _MODULES = {
     "qwen3-14b": "qwen3_14b",
     "xlstm-1.3b": "xlstm_1_3b",
     "whisper-tiny": "whisper_tiny",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCH_IDS = tuple(_MODULES)

@@ -67,10 +67,12 @@ def decode_attention(
     kv_len,
     window: Optional[int] = None,
     block_k: int = BLOCK_K,
+    scale: Optional[float] = None,
     interpret: bool = True,
 ) -> jnp.ndarray:
     """q: (B,1,H,hd); k/v: (B,T,K,hd); kv_len: scalar int (# valid entries,
-    including the token just written). Returns (B,1,H,hd)."""
+    including the token just written); ``scale`` multiplies the scores
+    (default 1/sqrt(hd)). Returns (B,1,H,hd)."""
     B, S, H, hd = q.shape
     assert S == 1, "decode kernel is single-token"
     T, K = k.shape[1], k.shape[2]
@@ -86,7 +88,7 @@ def decode_attention(
     len_arr = jnp.asarray(kv_len, jnp.int32).reshape(1)
 
     kernel = functools.partial(
-        _kernel, scale=hd ** -0.5, window=window, BK=BK, nk=nk
+        _kernel, scale=hd ** -0.5 if scale is None else scale, window=window, BK=BK, nk=nk
     )
     out = pl.pallas_call(
         kernel,

@@ -77,9 +77,11 @@ def flash_attention(
     window: Optional[int] = None,
     block_q: int = 128,
     block_k: int = 128,
+    scale: Optional[float] = None,
     interpret: bool = True,
 ) -> jnp.ndarray:
-    """q: (B,S,H,hd); k/v: (B,T,K,hd) -> (B,S,H,hd)."""
+    """q: (B,S,H,hd); k/v: (B,T,K,hd) -> (B,S,H,hd). ``scale`` multiplies
+    the scores (default 1/sqrt(hd))."""
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
@@ -94,7 +96,7 @@ def flash_attention(
     vh = v.transpose(0, 2, 1, 3).reshape(B * K, T, hd)
 
     kernel = functools.partial(
-        _kernel, scale=hd ** -0.5, causal=causal, window=window,
+        _kernel, scale=hd ** -0.5 if scale is None else scale, causal=causal, window=window,
         BQ=BQ, BK=BK, nk=nk,
     )
     out = pl.pallas_call(

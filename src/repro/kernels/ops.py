@@ -23,20 +23,21 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
+                                             "scale"))
 def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
-                    block_q: int = 128, block_k: int = 128):
+                    block_q: int = 128, block_k: int = 128, scale: Optional[float] = None):
     return _fa.flash_attention(
         q, k, v, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=_interpret(),
+        block_q=block_q, block_k=block_k, scale=scale, interpret=_interpret(),
     )
 
 
-@functools.partial(jax.jit, static_argnames=("window", "block_k"))
+@functools.partial(jax.jit, static_argnames=("window", "block_k", "scale"))
 def decode_attention(q, k, v, *, kv_len, window: Optional[int] = None,
-                     block_k: int = _dec.BLOCK_K):
+                     block_k: int = _dec.BLOCK_K, scale: Optional[float] = None):
     return _dec.decode_attention(
-        q, k, v, kv_len=kv_len, window=window, block_k=block_k,
+        q, k, v, kv_len=kv_len, window=window, block_k=block_k, scale=scale,
         interpret=_interpret(),
     )
 

@@ -17,6 +17,17 @@ from .layers import apply_rope, dense, dense_init, head_rmsnorm, rope_tables
 
 _NEG = -2.0e38
 
+# Orders of the layer stacks' axes after the layer axis, as permutations of
+# one layer's (B, S, K, hd) keys or values. SEQ_MAJOR, (S, K, B, hd), suits
+# heads of 128, which fill the TPU's lanes. SEQ_MINOR, (B, K, hd, S), puts
+# the sequence on the lanes, for narrower heads: with heads of 64 in
+# (S, K, B, hd) order, the v5e compiler keeps the stacks in another layout
+# inside the decode loop and copies them whole into it and back at every
+# step.
+SEQ_MAJOR = (1, 2, 0, 3)
+SEQ_MINOR = (0, 2, 3, 1)
+LANES = 128
+
 
 class KVCache(NamedTuple):
     """Decode cache keys and values. Per layer, k/v: (B, S_max, K, hd). The
@@ -83,13 +94,15 @@ def sdpa(
     q_offset=0,
     kv_len=None,
     kv_positions=None,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Reference GQA attention. q: (B,S,H,hd); k/v: (B,T,K,hd); H % K == 0."""
+    """Reference GQA attention. q: (B,S,H,hd); k/v: (B,T,K,hd); H % K == 0.
+    ``scale`` multiplies the scores (default 1/sqrt(hd))."""
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
     qg = q.reshape(B, S, K, G, hd)
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     logits = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32) * scale
     logits = logits + _mask(
         S, T, causal=causal, window=window, q_offset=q_offset,
@@ -116,6 +129,7 @@ def blockwise_sdpa(
     q_offset=0,
     q_chunk: int = 512,
     k_chunk: int = 1024,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Flash-style online-softmax attention in pure JAX (lax.scan over KV
     blocks, outer scan over Q blocks). O(S*hd) memory instead of O(S*T)."""
@@ -125,9 +139,9 @@ def blockwise_sdpa(
     qc = min(q_chunk, S)
     kc = min(k_chunk, T)
     if S % qc or T % kc:
-        return sdpa(q, k, v, causal=causal, window=window, q_offset=q_offset)
+        return sdpa(q, k, v, causal=causal, window=window, q_offset=q_offset, scale=scale)
     nq, nk = S // qc, T // kc
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     f32 = jnp.float32
 
     kb = k.reshape(B, nk, kc, K, hd)
@@ -189,6 +203,8 @@ def attention(
     kv_positions=None,
     kv_override: Optional[tuple] = None,
     layer=None,
+    kv_order: tuple = SEQ_MAJOR,
+    scale: Optional[float] = None,
     use_kernels: bool = False,
 ):
     """Full attention sub-layer: qkv proj -> rope -> sdpa -> out proj.
@@ -199,10 +215,13 @@ def attention(
       * decode: ``cache`` given, x is (B, 1, d); keys/values are inserted at
         ``cache_pos`` and attention runs over the cache prefix.
         With ``layer`` (a traced index), ``cache`` holds every layer's
-        (L, S_max, K, B, hd) stacks: only the new token's row of that layer
-        is written, the layer's slice is read in place, and the stacks are
-        returned.
+        stacks, (L, S_max, K, B, hd) or in another ``kv_order``: only the
+        new token's row of that layer is written, the layer's slice is read
+        in place, and the stacks are returned.
       * cross-attention: ``kv_override=(k, v)`` skips rope/cache.
+
+    ``scale`` multiplies the scores (default 1/sqrt(hd)); ``theta <= 0``
+    applies no rotary embedding (NoPE).
     """
     from ..hints import constrain
 
@@ -230,17 +249,18 @@ def attention(
 
     if kv_override is not None:
         with jax.named_scope("attend"):
-            out = sdpa(q, k, v, causal=False)
+            out = sdpa(q, k, v, causal=False, scale=scale)
         new_cache = None
     elif cache is None:
         with jax.named_scope("attend"):
             if use_kernels:
                 from ..kernels import ops as kops
-                out = kops.flash_attention(q, k, v, causal=causal, window=window)
+                out = kops.flash_attention(q, k, v, causal=causal, window=window,
+                                           scale=scale)
             elif S >= BLOCKWISE_THRESHOLD:
-                out = blockwise_sdpa(q, k, v, causal=causal, window=window)
+                out = blockwise_sdpa(q, k, v, causal=causal, window=window, scale=scale)
             else:
-                out = sdpa(q, k, v, causal=causal, window=window)
+                out = sdpa(q, k, v, causal=causal, window=window, scale=scale)
         new_cache = KVCache(k, v)
     else:
         # decode: write k/v at cache_write_pos (ring caches pass pos % W),
@@ -251,11 +271,10 @@ def attention(
                 ck = jax.lax.dynamic_update_slice(cache.k, k, (0, wp, 0, 0))
                 cv = jax.lax.dynamic_update_slice(cache.v, v, (0, wp, 0, 0))
             else:
-                # one (1, K, B, hd) row of the stacks changes; they stay in
-                # place through the layer scan
-                at = (layer, wp, 0, 0, 0)
-                ck = jax.lax.dynamic_update_slice(cache.k, seq_major(k)[None], at)
-                cv = jax.lax.dynamic_update_slice(cache.v, seq_major(v)[None], at)
+                # one position of the layer's slice changes; the stacks stay
+                # in place through the layer scan
+                ck = _write_position(cache.k, k, layer, wp, kv_order)
+                cv = _write_position(cache.v, v, layer, wp, kv_order)
         with jax.named_scope("attend"):
             if layer is None:
                 kc, vc = ck, cv
@@ -263,9 +282,10 @@ def attention(
                 # the layer's slice as (B, S_max, K, hd); the compiler folds
                 # the transpose into the attention's reads (re-laying the
                 # slice out as (B*K, S_max, hd) matrices first is slower)
+                back = tuple(kv_order.index(a) for a in range(4))
                 kc = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
                 vc = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
-                kc, vc = kc.transpose(2, 0, 1, 3), vc.transpose(2, 0, 1, 3)
+                kc, vc = kc.transpose(back), vc.transpose(back)
             if kv_positions is not None:
                 # ring cache: validity comes from the positions array
                 out = sdpa(
@@ -274,11 +294,12 @@ def attention(
                     window=window,
                     q_offset=cache_pos,
                     kv_positions=kv_positions,
+                    scale=scale,
                 )
             elif use_kernels:
                 from ..kernels import ops as kops
                 out = kops.decode_attention(
-                    q, kc, vc, kv_len=cache_pos + S, window=window
+                    q, kc, vc, kv_len=cache_pos + S, window=window, scale=scale
                 )
             else:
                 out = sdpa(
@@ -287,6 +308,7 @@ def attention(
                     window=window,
                     q_offset=cache_pos,
                     kv_len=cache_pos + S,
+                    scale=scale,
                 )
         new_cache = KVCache(ck, cv)
 
@@ -295,9 +317,26 @@ def attention(
     return y, new_cache
 
 
+def _write_position(stack, new, layer, pos, order):
+    """``stack`` (L, ...) in ``order`` with layer ``layer``'s position ``pos``
+    set to ``new`` (B, 1, K, hd). With the sequence last, on the lanes, the
+    aligned block of LANES positions that holds ``pos`` is rewritten whole:
+    an update one lane wide would force the stack into another layout."""
+    row = new.transpose(order)[None]
+    if order[-1] != 1:
+        at = (layer,) + tuple(pos if a == 1 else 0 for a in order)
+        return jax.lax.dynamic_update_slice(stack, row, at)
+    S = stack.shape[-1]
+    blk = LANES if S % LANES == 0 else S
+    at = (layer, 0, 0, 0, pos // blk * blk)
+    old = jax.lax.dynamic_slice(stack, at, row.shape[:-1] + (blk,))
+    hit = jnp.arange(blk) == pos % blk
+    return jax.lax.dynamic_update_slice(stack, jnp.where(hit, row, old), at)
+
+
 def seq_major(a):
     """(B, S, K, hd) -> (S, K, B, hd), the order of the layer stacks."""
-    return a.transpose(1, 2, 0, 3)
+    return a.transpose(SEQ_MAJOR)
 
 
 def empty_cache(cfg: ModelConfig, B: int, S_max: int, dtype) -> KVCache:

@@ -1,10 +1,26 @@
-"""Zamba2-style hybrid: Mamba2 backbone with a single weight-shared
-attention+MLP block applied after every ``shared_attn_every`` mamba layers.
+"""Mamba2 hybrids, sharing one mixer (``mamba2.mamba_forward``).
 
-Simplification vs. the released Zamba2 (noted in DESIGN.md): the shared block
-consumes the residual stream directly (no concat with the original embedding,
-no per-invocation LoRA). Structure (mamba backbone + periodically-invoked
-tied attention with its own KV cache per invocation site) is preserved.
+Zamba2-style (``shared_attn_every``): Mamba2 backbone with a single
+weight-shared attention+MLP block applied after every ``shared_attn_every``
+mamba layers. Simplification vs. the released Zamba2 (noted in DESIGN.md):
+the shared block consumes the residual stream directly (no concat with the
+original embedding, no per-invocation LoRA). Structure (mamba backbone +
+periodically-invoked tied attention with its own KV cache per invocation
+site) is preserved.
+
+Granite-4.0-H-style (``layer_types``, HF ``GraniteMoeHybrid`` without
+experts): every layer is a pre-norm mixer, Mamba2 or GQA attention (scale
+``attention_multiplier``, NoPE where ``nope``), then a pre-norm SwiGLU MLP;
+each sublayer's output is scaled by ``residual_multiplier`` before it joins
+the residual stream, the embeddings by ``embedding_multiplier``, and the
+logits divided by ``logits_scaling``. The pattern is ``groups`` repeats of
+one period holding one attention layer. The decode cache is four stacks:
+the mamba layers' conv windows (n_mamba, W-1, B, Ch) and float32 states
+(n_mamba, B, H, P, N), and the attention layers' keys and values
+(n_attn, B, K, hd, S_max), ``attention.SEQ_MINOR`` for heads of 64. They
+ride in the layer scans' carry, and each layer reads and writes its own
+slice in place.
+Scopes as in ``transformer.py``, with the mixer's ``ssm_*`` names.
 """
 
 from __future__ import annotations
@@ -15,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, ShapeSpec
-from .attention import KVCache, attention, attn_init
+from .attention import SEQ_MINOR, KVCache, attention, attn_init
 from .common import Model, remat_wrap, stack_init, token_specs
 from .layers import (
     cross_entropy_loss,
@@ -127,45 +143,17 @@ def loss_fn(params, batch, cfg: ModelConfig, *, remat=None, use_kernels=False):
 
 
 def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
-    """Prefill must also produce mamba states -> run layers with streaming
-    semantics: chunked SSD already yields the final state, so we re-run the
-    group scan keeping states."""
+    """Run the prompt, keeping each mamba layer's final conv window and state
+    and the shared block's K/V at every invocation site."""
     x = embed(params["embed"], batch["tokens"])
     B, S = x.shape[:2]
     positions = jnp.arange(S)
     shared = params["shared"]
-    dtype = dtype_of(cfg)
 
-    def m_layer_with_state(lp, xc):
-        xn = rmsnorm(lp["norm"], xc, cfg.norm_eps)
-        # run chunked and also extract final conv window + ssm state
-        from .mamba2 import _causal_conv, ssd_chunked
-        from .layers import dense as _dense
-        di, N, H, P, W = (
-            cfg.d_inner_ssm, cfg.ssm_state, cfg.n_ssm_heads,
-            cfg.ssm_head_dim, cfg.ssm_conv_width,
-        )
-        zxbcdt = _dense(lp["mamba"]["in_proj"], xn)
-        z, xBC, dt_raw = jnp.split(zxbcdt, [di, 2 * di + 2 * N], axis=-1)
-        conv_tail = xBC[:, -(W - 1):, :]
-        xBC_c = jax.nn.silu(_causal_conv(xBC, lp["mamba"]["conv_w"], lp["mamba"]["conv_b"]))
-        from ..hints import constrain
-        xs, B_in, C_in = jnp.split(xBC_c, [di, di + N], axis=-1)
-        xs = constrain(xs.reshape(B, S, H, P), "dp", None, "model", None)
-        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + lp["mamba"]["dt_bias"])
-        A = -jnp.exp(lp["mamba"]["A_log"])
-        y, hT = ssd_chunked(xs, dt, A, B_in, C_in, cfg.ssm_chunk)
-        y = y + lp["mamba"]["D"].astype(y.dtype)[None, None, :, None] * xs
-        y = y.reshape(B, S, di)
-        y = rmsnorm(lp["mamba"]["gnorm"], y * jax.nn.silu(z), cfg.norm_eps)
-        out = _dense(lp["mamba"]["out_proj"], y)
-        return xc + out, MambaCache(conv=conv_tail, h=hT)
+    def inner(xc, lp):
+        return _mamba_layer(lp, xc, cfg, use_kernels=use_kernels)
 
     def group(x, gp):
-        def inner(xc, lp):
-            xc, st = m_layer_with_state(lp, xc)
-            return xc, st
-
         x, states = jax.lax.scan(inner, x, gp)
         x, kv = _shared_apply(shared, x, cfg, positions=positions)
         return x, (states, kv)
@@ -173,9 +161,6 @@ def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
     x, (g_states, skv) = jax.lax.scan(group, x, params["mamba_groups"])
     t_states = None
     if "mamba_tail" in params:
-        def inner(xc, lp):
-            xc, st = m_layer_with_state(lp, xc)
-            return xc, st
         x, t_states = jax.lax.scan(inner, x, params["mamba_tail"])
 
     h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
@@ -263,17 +248,243 @@ def init_cache(cfg: ModelConfig, B: int, S_max: int):
     return cache
 
 
+# ---------------------------------------------------------------------------
+# granite-4.0-h: a pattern of mamba2 and attention layers, each with an MLP
+# ---------------------------------------------------------------------------
+def _pattern(cfg: ModelConfig) -> tuple[int, int, int]:
+    """(groups, mamba layers before each group's attention layer, after it)."""
+    types = tuple(cfg.layer_types)
+    G = types.count("attention")
+    pre = types.index("attention") if G else 0
+    post = len(types) // max(G, 1) - pre - 1
+    if not G or ((("mamba",) * pre + ("attention",) + ("mamba",) * post) * G) != types:
+        raise ValueError(f"{cfg.name}: layer_types is not a repeated period with "
+                         "one attention layer")
+    return G, pre, post
+
+
+def _gh_layer_init(rng, cfg: ModelConfig, dtype, mixer: str):
+    rm, rf = jax.random.split(rng)
+    p = {
+        "ln1": rmsnorm_init(cfg.d_model, dtype),
+        "ln2": rmsnorm_init(cfg.d_model, dtype),
+        "mlp": swiglu_init(rf, cfg.d_model, cfg.d_ff, dtype=dtype),
+    }
+    if mixer == "mamba":
+        p["mamba"] = mamba_init(rm, cfg, dtype=dtype)
+    else:
+        p["attn"] = attn_init(rm, cfg, dtype=dtype)
+    return p
+
+
+def _gh_init(rng, cfg: ModelConfig):
+    dtype = dtype_of(cfg)
+    G = _pattern(cfg)[0]
+    r_emb, r_m, r_a, r_un = jax.random.split(rng, 4)
+    params = {
+        "embed": embed_init(r_emb, cfg.padded_vocab, cfg.d_model, dtype),
+        "final_norm": rmsnorm_init(cfg.d_model, dtype),
+        "mamba_layers": stack_init(r_m, cfg.n_layers - G, functools.partial(
+            _gh_layer_init, cfg=cfg, dtype=dtype, mixer="mamba")),
+        "attn_layers": stack_init(r_a, G, functools.partial(
+            _gh_layer_init, cfg=cfg, dtype=dtype, mixer="attention")),
+    }
+    if not cfg.tie_embeddings:
+        params["unembed"] = embed_init(r_un, cfg.padded_vocab, cfg.d_model, dtype)
+    return params
+
+
+def _gh_embed(params, tokens, cfg: ModelConfig):
+    with jax.named_scope("embed"):
+        return embed(params["embed"], tokens) * cfg.embedding_multiplier
+
+
+def _gh_logits(params, x, cfg: ModelConfig):
+    with jax.named_scope("norm"):
+        h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        return unembed(params.get("unembed", params["embed"]), h) / cfg.logits_scaling
+
+
+def _gh_mlp(lp, x, cfg: ModelConfig):
+    with jax.named_scope("norm"):
+        y = rmsnorm(lp["ln2"], x, cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        return x + swiglu(lp["mlp"], y) * cfg.residual_multiplier
+
+
+def _gh_mamba(lp, x, cfg: ModelConfig, cache=None, use_kernels=False):
+    """One mamba layer and its MLP; returns (x, MambaCache)."""
+    with jax.named_scope("norm"):
+        xn = rmsnorm(lp["ln1"], x, cfg.norm_eps)
+    h, state = mamba_forward(lp["mamba"], xn, cfg, cache=cache, use_kernels=use_kernels)
+    return _gh_mlp(lp, x + h * cfg.residual_multiplier, cfg), state
+
+
+def _gh_attn(lp, x, cfg: ModelConfig, *, positions, cache=None, cache_pos=None,
+             layer=None, use_kernels=False):
+    """One attention layer and its MLP; returns (x, KVCache)."""
+    with jax.named_scope("norm"):
+        xn = rmsnorm(lp["ln1"], x, cfg.norm_eps)
+    h, kv = attention(
+        lp["attn"], xn, cfg, positions=positions,
+        theta=0.0 if cfg.nope else cfg.rope_theta, scale=cfg.attention_multiplier,
+        cache=cache, cache_pos=cache_pos, layer=layer, kv_order=SEQ_MINOR,
+        use_kernels=use_kernels,
+    )
+    return _gh_mlp(lp, x + h * cfg.residual_multiplier, cfg), kv
+
+
+def _take(tree, i):
+    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
+
+
+def _put(stack, i, a):
+    """``stack`` with slice ``i`` replaced by ``a``, in place."""
+    return jax.lax.dynamic_update_slice(stack, a[None].astype(stack.dtype),
+                                        (i,) + (0,) * a.ndim)
+
+
+def _gh_layers(params, x, m_state, a_state, cfg: ModelConfig, mamba_fn, attn_fn):
+    """The layers in pattern order: a scan over the groups, and in each the
+    mamba layers before its attention layer, the attention layer, and the
+    mamba layers after. ``mamba_fn(lp, x, m_state, m)`` and
+    ``attn_fn(lp, x, a_state, g)`` return (x, their state). The mamba
+    layers' state rides in every scan's carry, the attention layers' in the
+    group scan's only."""
+    G, pre, post = _pattern(cfg)
+
+    def m_body(carry, m):
+        x, st = carry
+        return mamba_fn(_take(params["mamba_layers"], m), x, st, m), None
+
+    def g_body(carry, g):
+        x, m_st, a_st = carry
+        first = g * (pre + post)
+        (x, m_st), _ = jax.lax.scan(m_body, (x, m_st), first + jnp.arange(pre))
+        x, a_st = attn_fn(_take(params["attn_layers"], g), x, a_st, g)
+        (x, m_st), _ = jax.lax.scan(m_body, (x, m_st), first + pre + jnp.arange(post))
+        return (x, m_st, a_st), None
+
+    with jax.named_scope("layers"):
+        (x, m_state, a_state), _ = jax.lax.scan(g_body, (x, m_state, a_state),
+                                                jnp.arange(G))
+    return x, m_state, a_state
+
+
+def granite_forward(params, tokens, cfg: ModelConfig, *, remat=None, use_kernels=False):
+    """Logits at every position of ``tokens`` (B, S), with no cache."""
+    x = _gh_embed(params, tokens, cfg)
+    positions = jnp.arange(x.shape[1])
+    mamba = remat_wrap(
+        lambda lp, x: _gh_mamba(lp, x, cfg, use_kernels=use_kernels)[0], remat)
+    attn = remat_wrap(
+        lambda lp, x: _gh_attn(lp, x, cfg, positions=positions,
+                               use_kernels=use_kernels)[0], remat)
+    x, _, _ = _gh_layers(params, x, (), (), cfg,
+                         lambda lp, x, st, m: (mamba(lp, x), st),
+                         lambda lp, x, st, g: (attn(lp, x), st))
+    return _gh_logits(params, x, cfg)
+
+
+def _gh_loss(params, batch, cfg: ModelConfig, *, remat=None, use_kernels=False):
+    logits = granite_forward(params, batch["tokens"], cfg, remat=remat,
+                             use_kernels=use_kernels)
+    ce = cross_entropy_loss(logits, batch["labels"])
+    return ce, {"ce": ce, "aux": 0.0}
+
+
+def _gh_prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
+    """Run the prompt; the cache holds every layer's final conv window and
+    state, and the attention layers' K/V in the decode loop's layout."""
+    x = _gh_embed(params, batch["tokens"], cfg)
+    B, S = x.shape[:2]
+    positions = jnp.arange(S)
+    c = _gh_init_cache(cfg, B, S_max)
+
+    def mamba_fn(lp, x, st, m):
+        conv, h = st
+        x, ms = _gh_mamba(lp, x, cfg, use_kernels=use_kernels)
+        with jax.named_scope("ssm_conv"):
+            conv = _put(conv, m, ms.conv.swapaxes(0, 1))
+        with jax.named_scope("ssm_scan"):
+            h = _put(h, m, ms.h)
+        return x, (conv, h)
+
+    def attn_fn(lp, x, st, g):
+        k, v = st
+        x, kv = _gh_attn(lp, x, cfg, positions=positions, use_kernels=use_kernels)
+        with jax.named_scope("kv_write"):
+            k, v = _put(k, g, kv.k.transpose(SEQ_MINOR)), _put(v, g, kv.v.transpose(SEQ_MINOR))
+        return x, (k, v)
+
+    x, (conv, h), (k, v) = _gh_layers(params, x, (c["conv"], c["h"]), (c["k"], c["v"]),
+                                      cfg, mamba_fn, attn_fn)
+    logits = _gh_logits(params, x[:, -1], cfg)
+    return logits, {"conv": conv, "h": h, "k": k, "v": v, "pos": jnp.int32(S)}
+
+
+def _gh_decode(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
+    """One token for every sequence; each layer updates its slice of the
+    carried stacks in place."""
+    x = _gh_embed(params, batch["token"][:, None], cfg)
+    pos = cache["pos"]
+    positions = pos[None]
+
+    def mamba_fn(lp, x, st, m):
+        conv, h = st
+        with jax.named_scope("ssm_conv"):
+            c = jax.lax.dynamic_index_in_dim(conv, m, keepdims=False).swapaxes(0, 1)
+        with jax.named_scope("ssm_state"):
+            hm = jax.lax.dynamic_index_in_dim(h, m, keepdims=False)
+        x, ms = _gh_mamba(lp, x, cfg, cache=MambaCache(c, hm), use_kernels=use_kernels)
+        with jax.named_scope("ssm_conv"):
+            conv = _put(conv, m, ms.conv.swapaxes(0, 1))
+        with jax.named_scope("ssm_state"):
+            h = _put(h, m, ms.h)
+        return x, (conv, h)
+
+    def attn_fn(lp, x, st, g):
+        x, kv = _gh_attn(lp, x, cfg, positions=positions, cache=KVCache(*st),
+                         cache_pos=pos, layer=g, use_kernels=use_kernels)
+        return x, tuple(kv)
+
+    x, (conv, h), (k, v) = _gh_layers(
+        params, x, (cache["conv"], cache["h"]), (cache["k"], cache["v"]), cfg,
+        mamba_fn, attn_fn)
+    logits = _gh_logits(params, x[:, 0], cfg)
+    return logits, {"conv": conv, "h": h, "k": k, "v": v, "pos": pos + 1}
+
+
+def _gh_init_cache(cfg: ModelConfig, B: int, S_max: int):
+    G = _pattern(cfg)[0]
+    mc = empty_mamba_cache(cfg, B, dtype_of(cfg))
+    kv = (G, B, cfg.n_kv_heads, cfg.hd, S_max)          # SEQ_MINOR
+    return {
+        "conv": jnp.zeros((cfg.n_layers - G,) + mc.conv.swapaxes(0, 1).shape, mc.conv.dtype),
+        "h": jnp.zeros((cfg.n_layers - G,) + mc.h.shape, mc.h.dtype),
+        "k": jnp.zeros(kv, dtype_of(cfg)),
+        "v": jnp.zeros(kv, dtype_of(cfg)),
+        "pos": jnp.int32(0),
+    }
+
+
 def input_specs(cfg: ModelConfig, shape: ShapeSpec) -> dict:
     return token_specs(shape)
 
 
 def build(cfg: ModelConfig) -> Model:
+    if cfg.layer_types:
+        fns = (_gh_init, _gh_loss, _gh_prefill, _gh_decode, _gh_init_cache)
+    else:
+        fns = (init, loss_fn, prefill, decode_step, init_cache)
+    i, lo, pf, dec, ic = fns
     return Model(
         cfg=cfg,
-        init=functools.partial(init, cfg=cfg),
-        loss=functools.partial(loss_fn, cfg=cfg),
-        prefill=functools.partial(prefill, cfg=cfg),
-        decode_step=functools.partial(decode_step, cfg=cfg),
-        init_cache=functools.partial(init_cache, cfg),
+        init=functools.partial(i, cfg=cfg),
+        loss=functools.partial(lo, cfg=cfg),
+        prefill=functools.partial(pf, cfg=cfg),
+        decode_step=functools.partial(dec, cfg=cfg),
+        init_cache=functools.partial(ic, cfg),
         input_specs=functools.partial(input_specs, cfg),
     )

@@ -7,7 +7,10 @@ O(1) in depth; gemma3 uses a nested scan over (blocks x [R local + 1 global]).
 Train, prefill and decode name their work with ``jax.named_scope`` from one
 vocabulary, which profiler traces carry as each op's ``op_name`` path:
 ``embed``, ``layers`` (the layer scan), ``norm``, ``attn_proj``, ``kv_write``,
-``attend``, ``mlp`` or ``moe``, and ``lm_head``. Scopes are HLO metadata only;
+``attend``, ``mlp`` or ``moe``, and ``lm_head``; the Mamba2 mixer of the
+hybrids (``mamba2.py``) adds ``ssm_proj`` (in/out projections and the gated
+norm), ``ssm_conv``, ``ssm_scan`` (the prefill's chunked SSD) and
+``ssm_state`` (the decode step's state update). Scopes are HLO metadata only;
 the compiled program is the same without them.
 """
 

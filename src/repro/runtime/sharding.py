@@ -85,6 +85,7 @@ _PARAM_RULES: list[tuple[str, tuple]] = [
     ("up/w", (None, MODEL)),
     ("down/w", (MODEL, None)),
     ("in_proj/w", (None, MODEL)),
+    ("dt_proj/w", (None, MODEL)),
     ("out_proj/w", (MODEL, None)),
     ("conv_w", (None, MODEL)),
     ("conv_b", (MODEL,)),
@@ -104,6 +105,8 @@ _STACK_DIMS = {
     "global_layers": 1,
     "mamba_groups": 2,
     "mamba_tail": 1,
+    "mamba_layers": 1,
+    "attn_layers": 1,
     "shared": 0,
     "slstm": 1,
     "mlstm": 2,
@@ -207,6 +210,14 @@ def cache_shardings(mesh: Mesh, cache_like, cfg):
         spec: list = [None] * len(shape)
         if leaf.ndim == 0:
             return NamedSharding(mesh, P())
+        if cfg.layer_types and name in ("k", "v", "h", "conv"):
+            # granite-h: k/v (L, B, K, hd, S) and states (L, B, H, P, N) shard
+            # batch over dp and heads over model; conv windows (L, W-1, B, Ch)
+            if name == "conv":
+                spec[-2] = dp
+            else:
+                spec[-4], spec[-3] = dp, MODEL
+            return NamedSharding(mesh, _sanitize(mesh, spec, shape))
         if uniform_stack and name in ("k", "v"):
             spec[-2] = dp
             spec[-4] = MODEL

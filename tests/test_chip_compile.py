@@ -1,5 +1,6 @@
 """Every Pallas kernel compiles natively for a TPU v5e at published widths,
-and the decode step keeps its KV cache in place.
+the decode step keeps its KV cache in place, and granite-4.0-h-micro's
+serve steps fit one chip at the batch its benchmark cell serves.
 
 Nothing runs: the TPU compiler, which is installed with jaxlib, compiles for
 a described v5e chip that is not attached. That catches what interpret mode
@@ -26,9 +27,11 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssd_scan as _ssd
 from repro.models import build_model
-from repro.runtime import RuntimeConfig, jit_decode_step
+from repro.launch.serve import cache_len
+from repro.runtime import RuntimeConfig, jit_decode_step, jit_prefill
 
 PHI4 = get_config("phi4-mini-3.8b")
+GRANITE_H = get_config("granite-4.0-h-micro")
 ZAMBA2 = get_config("zamba2-7b")
 BF16 = jnp.bfloat16
 
@@ -111,3 +114,41 @@ def test_decode_step_keeps_cache_in_place(one_chip):
     # xs and ys makes four a step, restacking the ys and copying them out
     copies = re.findall(r"%(copy[\w.\-]*) = " + re.escape(stack), compiled.as_text())
     assert not copies, copies
+
+
+def test_granite_h_serve_steps_fit_one_chip(one_chip):
+    """granite-4.0-h-micro at full size, at the benchmark cell's shapes (24
+    sequences, prompts of 4096, up to 512 new tokens): prefill and decode
+    each fit the chip's 15.75 GB, and the decode step's temporaries are
+    smaller than one Mamba layer's state slice, so neither the SSM state nor
+    the KV stacks are copied."""
+    cfg, B, P = GRANITE_H, 24, 4096
+    S_max = cache_len(P + 512)
+    model = build_model(cfg)
+    params_like = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    batch_like = {"tokens": jax.ShapeDtypeStruct((B, P), jnp.int32)}
+    cache_like = jax.eval_shape(lambda p, b: model.prefill(p, b, S_max), params_like,
+                                batch_like)[1]
+    tok_like = {"token": jax.ShapeDtypeStruct((B,), jnp.int32)}
+    mesh = Mesh(np.array([*one_chip.device_set]).reshape(1, 1), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    prefill, *_ = jit_prefill(model, mesh, RuntimeConfig(), S_max, params_like, batch_like,
+                              cache_like)
+    decode, *_ = jit_decode_step(model, mesh, RuntimeConfig(), params_like, cache_like,
+                                 tok_like)
+    pre = prefill.lower(params_like, batch_like).compile()
+    dec = decode.lower(params_like, cache_like, tok_like).compile()
+    for compiled in (pre, dec):
+        m = compiled.memory_analysis()
+        total = (m.argument_size_in_bytes + m.output_size_in_bytes
+                 + m.temp_size_in_bytes - m.alias_size_in_bytes)
+        assert total < 15.75e9, total
+    state_slice = B * cfg.n_ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4   # 2.10 MB each
+    assert dec.memory_analysis().temp_size_in_bytes < state_slice
+    text = dec.as_text()
+    for name in ("conv", "h", "k", "v"):
+        leaf = cache_like[name]
+        stack = {"float32": "f32", "bfloat16": "bf16"}[str(leaf.dtype)] + "[" \
+            + ",".join(map(str, leaf.shape)) + "]"
+        copies = re.findall(r"%(copy[\w.\-]*) = " + re.escape(stack), text)
+        assert not copies, (name, copies)

@@ -114,6 +114,51 @@ def test_cache_shardings_follow_the_stack_layout():
     """)
 
 
+def test_granite_h_serves_on_a_mesh():
+    """granite-4.0-h's stacks shard batch over data (heads over model where
+    they divide), and prefill plus decode on a (2, 4) mesh give the
+    one-device logits."""
+    _run("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import PartitionSpec as P
+        from repro.configs import get_smoke
+        from repro.models import build_model
+        from repro.runtime import RuntimeConfig, cache_shardings, jit_decode_step, jit_prefill
+        from repro.launch.mesh import make_host_mesh
+
+        model = build_model(get_smoke("granite-4.0-h-micro"))
+        B, S, S_max = 8, 24, 64
+        cache = jax.eval_shape(lambda: model.init_cache(B, S_max))
+        sh = cache_shardings(make_host_mesh(2, 4), cache, model.cfg)
+        assert sh["h"].spec == P(None, "data", "model", None, None), sh["h"].spec
+        assert sh["conv"].spec == P(None, None, "data", None), sh["conv"].spec
+        assert sh["k"].spec == P(None, "data", None, None, None), sh["k"].spec
+
+        params = model.init(jax.random.PRNGKey(0))
+        toks = jax.random.randint(jax.random.PRNGKey(1), (B, S + 3), 0, model.cfg.vocab_size)
+        batch = {"tokens": toks[:, :S]}
+        cache_like = jax.eval_shape(lambda p, b: model.prefill(p, b, S_max), params, batch)[1]
+        tok_like = {"token": jax.ShapeDtypeStruct((B,), jnp.int32)}
+        outs = []
+        for dp, tp in ((1, 1), (2, 4)):
+            mesh = make_host_mesh(dp, tp)
+            pre, p_sh, b_sh, _ = jit_prefill(model, mesh, RuntimeConfig(), S_max, params,
+                                             batch, cache_like)
+            dec, *_ = jit_decode_step(model, mesh, RuntimeConfig(), params, cache_like,
+                                      tok_like)
+            p = jax.device_put(params, p_sh)
+            logits, c = pre(p, jax.device_put(batch, b_sh))
+            got = [logits]
+            for t in range(3):
+                logits, c = dec(p, c, {"token": toks[:, S + t]})
+                got.append(logits)
+            outs.append(np.stack([np.asarray(g) for g in got]))
+        err = np.max(np.abs(outs[0] - outs[1])) / np.max(np.abs(outs[0]))
+        assert err < 1e-4, err
+        print("OK")
+    """)
+
+
 def test_dryrun_cell_small_mesh_moe():
     """MoE lowering + compile + roofline extraction on a small mesh —
     the dry-run machinery itself, in miniature."""

@@ -48,6 +48,14 @@ def test_model_config_cuts_depth_only():
         common.model_config("granite-moe-1b-a400m", full=True, layers=25)
 
 
+def test_model_config_cuts_granite_h_by_whole_periods():
+    cut = common.model_config("granite-4.0-h-micro", full=True, layers=20)
+    assert cut.layer_types == common.model_config("granite-4.0-h-micro", full=True).layer_types[:20]
+    assert cut.layer_types.count("attention") == 2
+    with pytest.raises(ValueError):
+        common.model_config("granite-4.0-h-micro", full=True, layers=15)
+
+
 def test_serve_kernel_path_matches_jnp_path():
     """Same seeded weights through publish, stage and restore; the Pallas
     path (interpret mode here) and the jnp path agree."""

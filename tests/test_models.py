@@ -139,7 +139,8 @@ def test_decode_kernel_path_matches_jnp():
     np.testing.assert_allclose(np.asarray(ker["k"]), np.asarray(ref["k"]), rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "zamba2-7b", "gemma3-12b"])
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "zamba2-7b", "gemma3-12b",
+                                  "granite-4.0-h-micro"])
 def test_kernel_path_matches_reference(arch):
     cfg = get_smoke(arch)
     model = build_model(cfg)
@@ -199,8 +200,9 @@ def test_blockwise_equals_dense_attention():
 
 def test_param_count_matches_configs():
     """Analytic param_count (used for roofline MODEL_FLOPS) tracks actual
-    init within 12% for dense archs (padding + analytic approximations)."""
-    for arch in ("phi4-mini-3.8b", "qwen3-14b"):
+    init within 12% for dense archs (padding + analytic approximations), and
+    for both hybrids (granite-4.0-h's layer pattern, zamba2's shared block)."""
+    for arch in ("phi4-mini-3.8b", "qwen3-14b", "granite-4.0-h-micro", "zamba2-7b"):
         cfg = get_smoke(arch)
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))

@@ -1,6 +1,7 @@
 """The serve step's named scopes: module names, the scope vocabulary in the
-compiled program's ``op_name`` metadata, and proof that the scopes change
-nothing else in the compiled program."""
+compiled program's ``op_name`` metadata (the dense model's, and the Mamba2
+mixer's names in granite-4.0-h's), and proof that the scopes change nothing
+else in the compiled program."""
 
 import contextlib
 import re
@@ -17,13 +18,17 @@ from repro.runtime import RuntimeConfig, jit_decode_step, jit_prefill
 # The names each step uses on the dense jnp path.
 PREFILL = {"embed", "layers", "norm", "attn_proj", "attend", "mlp", "lm_head"}
 DECODE = PREFILL | {"kv_write"}
+# granite-4.0-h adds the mixer's names
+HYBRID = "granite-4.0-h-micro"
+SSM_PREFILL = PREFILL | {"kv_write", "ssm_proj", "ssm_conv", "ssm_scan"}
+SSM_DECODE = DECODE | {"ssm_proj", "ssm_conv", "ssm_state"}
 # Source-location tables that open the HLO text; they name the caller's lines.
 TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
 
 
-def _compiled_texts():
-    """The compiled HLO text of the smoke dense config's prefill and decode."""
-    model = build_model(get_smoke("phi4-mini-3.8b"))
+def _compiled_texts(arch="phi4-mini-3.8b"):
+    """The compiled HLO text of a smoke config's prefill and decode."""
+    model = build_model(get_smoke(arch))
     rt, mesh = RuntimeConfig(), make_host_mesh(1, 1)
     B, P, S_max = 2, 16, 64
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -47,6 +52,11 @@ def texts():
     return _compiled_texts()
 
 
+@pytest.fixture(scope="module")
+def hybrid_texts():
+    return _compiled_texts(HYBRID)
+
+
 def test_module_names(texts):
     assert texts["prefill"].startswith("HloModule jit_prefill,")
     assert texts["decode_step"].startswith("HloModule jit_decode_step,")
@@ -60,6 +70,14 @@ def test_vocabulary_in_op_names(texts, step, names):
     assert names <= seen, names - seen
 
 
+@pytest.mark.parametrize("step,names", [("prefill", SSM_PREFILL),
+                                        ("decode_step", SSM_DECODE)])
+def test_mixer_names_in_op_names(hybrid_texts, step, names):
+    paths = re.findall(r'op_name="([^"]*)"', hybrid_texts[step])
+    seen = {part for p in paths for part in p.split("/")}
+    assert names <= seen, names - seen
+
+
 def test_scopes_change_nothing_but_metadata(texts, monkeypatch):
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
     bare = _compiled_texts()
@@ -67,3 +85,21 @@ def test_scopes_change_nothing_but_metadata(texts, monkeypatch):
         assert "metadata=" in text
         assert _without_metadata(bare[step]) == _without_metadata(text), step
         assert "/layers/" not in bare[step]
+
+
+def _renumbered(text: str) -> str:
+    """Instruction names numbered in their order of appearance: the compiler
+    numbers the hybrid's transposes in the order it meets their scopes."""
+    names = {}
+    return re.sub(r"%([\w\-]+?)\.(\d+)\b",
+                  lambda m: "%" + m.group(1) + "." + names.setdefault(m.group(0), str(len(names))),
+                  text)
+
+
+def test_mixer_scopes_change_nothing_but_metadata(hybrid_texts, monkeypatch):
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    bare = _compiled_texts(HYBRID)
+    for step, text in hybrid_texts.items():
+        assert "/ssm_" in text and "/ssm_" not in bare[step]
+        assert _renumbered(_without_metadata(bare[step])) == \
+            _renumbered(_without_metadata(text)), step
