@@ -17,23 +17,39 @@ from .layers import apply_rope, dense, dense_init, head_rmsnorm, rope_tables
 
 _NEG = -2.0e38
 
-# Orders of the layer stacks' axes after the layer axis, as permutations of
-# one layer's (B, S, K, hd) keys or values. SEQ_MAJOR, (S, K, B, hd), suits
-# heads of 128, which fill the TPU's lanes. SEQ_MINOR, (B, K, hd, S), puts
-# the sequence on the lanes, for narrower heads: with heads of 64 in
-# (S, K, B, hd) order, the v5e compiler keeps the stacks in another layout
-# inside the decode loop and copies them whole into it and back at every
-# step.
-SEQ_MAJOR = (1, 2, 0, 3)
+# Orders of the decode cache stacks' axes after the layer axis, as
+# permutations of one layer's (B, S, K, hd) keys or values; ``kv_order_for``
+# picks one from the head width. HEAD_ROWS, (B, K, S, hd), is for heads that
+# fill the TPU's lanes: each (b, k) pair's keys are S_max rows of hd lanes,
+# which the decode step's dots read in place. SEQ_MINOR, (B, K, hd, S), puts
+# the sequence on the lanes for narrower heads. Either way the v5e compiler
+# keeps the stacks in their own layout through the decode loop only where the
+# sequence sits on the tiled axes and each step rewrites an aligned block
+# (``_write_position``); otherwise it copies both stacks whole into another
+# layout and back at every step (heads of 64 sequence-major, or heads of 128
+# in SEQ_MINOR).
+HEAD_ROWS = (0, 2, 1, 3)
 SEQ_MINOR = (0, 2, 3, 1)
 LANES = 128
+SUBLANES = 8
+
+
+def kv_order_for(hd: int) -> tuple:
+    """The stacks' order for heads of ``hd``."""
+    return HEAD_ROWS if hd % LANES == 0 else SEQ_MINOR
+
+
+def kv_stack_shape(n: int, B: int, S_max: int, K: int, hd: int) -> tuple:
+    """Shape of ``n`` layers' keys (or values) in ``kv_order_for(hd)``."""
+    return (n,) + tuple((B, S_max, K, hd)[a] for a in kv_order_for(hd))
 
 
 class KVCache(NamedTuple):
     """Decode cache keys and values. Per layer, k/v: (B, S_max, K, hd). The
-    transformer's uniform layer stack keeps all layers in one pair of
-    (L, S_max, K, B, hd) stacks instead, the layout its decode loop reads in
-    place (``attention(..., layer=...)``)."""
+    transformer's uniform layer stack and granite-H's attention layers keep
+    all layers in one pair of (L, ...) stacks in ``kv_order_for(hd)``
+    instead, which the decode loop reads in place
+    (``attention(..., layer=...)``)."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -203,7 +219,6 @@ def attention(
     kv_positions=None,
     kv_override: Optional[tuple] = None,
     layer=None,
-    kv_order: tuple = SEQ_MAJOR,
     scale: Optional[float] = None,
     use_kernels: bool = False,
 ):
@@ -215,9 +230,9 @@ def attention(
       * decode: ``cache`` given, x is (B, 1, d); keys/values are inserted at
         ``cache_pos`` and attention runs over the cache prefix.
         With ``layer`` (a traced index), ``cache`` holds every layer's
-        stacks, (L, S_max, K, B, hd) or in another ``kv_order``: only the
-        new token's row of that layer is written, the layer's slice is read
-        in place, and the stacks are returned.
+        stacks in ``kv_order_for(hd)``: only the new token's row of that
+        layer is written, the layer's slice is read in place, and the stacks
+        are returned.
       * cross-attention: ``kv_override=(k, v)`` skips rope/cache.
 
     ``scale`` multiplies the scores (default 1/sqrt(hd)); ``theta <= 0``
@@ -266,6 +281,7 @@ def attention(
         # decode: write k/v at cache_write_pos (ring caches pass pos % W),
         # attend over the valid region.
         wp = cache_pos if cache_write_pos is None else cache_write_pos
+        kv_order = kv_order_for(hd)
         with jax.named_scope("kv_write"):
             if layer is None:
                 ck = jax.lax.dynamic_update_slice(cache.k, k, (0, wp, 0, 0))
@@ -319,24 +335,20 @@ def attention(
 
 def _write_position(stack, new, layer, pos, order):
     """``stack`` (L, ...) in ``order`` with layer ``layer``'s position ``pos``
-    set to ``new`` (B, 1, K, hd). With the sequence last, on the lanes, the
-    aligned block of LANES positions that holds ``pos`` is rewritten whole:
-    an update one lane wide would force the stack into another layout."""
+    set to ``new`` (B, 1, K, hd). The sequence lies on the lanes or the
+    sublanes, and the aligned block of LANES or SUBLANES positions that holds
+    ``pos`` is rewritten whole: an update one lane or one sublane wide would
+    force the stack into another layout."""
     row = new.transpose(order)[None]
-    if order[-1] != 1:
-        at = (layer,) + tuple(pos if a == 1 else 0 for a in order)
-        return jax.lax.dynamic_update_slice(stack, row, at)
-    S = stack.shape[-1]
-    blk = LANES if S % LANES == 0 else S
-    at = (layer, 0, 0, 0, pos // blk * blk)
-    old = jax.lax.dynamic_slice(stack, at, row.shape[:-1] + (blk,))
-    hit = jnp.arange(blk) == pos % blk
+    ax = 1 + order.index(1)                 # 4: lanes, 3: sublanes
+    S = stack.shape[ax]
+    blk = LANES if ax == 4 else SUBLANES
+    blk = blk if S % blk == 0 else S
+    at = tuple(pos // blk * blk if i == ax else layer if i == 0 else 0 for i in range(5))
+    size = row.shape[:ax] + (blk,) + row.shape[ax + 1:]
+    old = jax.lax.dynamic_slice(stack, at, size)
+    hit = (jnp.arange(blk) == pos % blk).reshape((blk,) + (1,) * (4 - ax))
     return jax.lax.dynamic_update_slice(stack, jnp.where(hit, row, old), at)
-
-
-def seq_major(a):
-    """(B, S, K, hd) -> (S, K, B, hd), the order of the layer stacks."""
-    return a.transpose(SEQ_MAJOR)
 
 
 def empty_cache(cfg: ModelConfig, B: int, S_max: int, dtype) -> KVCache:

@@ -17,9 +17,9 @@ logits divided by ``logits_scaling``. The pattern is ``groups`` repeats of
 one period holding one attention layer. The decode cache is four stacks:
 the mamba layers' conv windows (n_mamba, W-1, B, Ch) and float32 states
 (n_mamba, B, H, P, N), and the attention layers' keys and values
-(n_attn, B, K, hd, S_max), ``attention.SEQ_MINOR`` for heads of 64. They
-ride in the layer scans' carry, and each layer reads and writes its own
-slice in place.
+(n_attn, B, K, hd, S_max), ``attention.kv_order_for``'s ``SEQ_MINOR`` for
+heads of 64. They ride in the layer scans' carry, and each layer reads and
+writes its own slice in place.
 Scopes as in ``transformer.py``, with the mixer's ``ssm_*`` names.
 """
 
@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, ShapeSpec
-from .attention import SEQ_MINOR, KVCache, attention, attn_init
+from .attention import KVCache, attention, attn_init, kv_order_for, kv_stack_shape
 from .common import Model, remat_wrap, stack_init, token_specs
 from .layers import (
     cross_entropy_loss,
@@ -329,8 +329,7 @@ def _gh_attn(lp, x, cfg: ModelConfig, *, positions, cache=None, cache_pos=None,
     h, kv = attention(
         lp["attn"], xn, cfg, positions=positions,
         theta=0.0 if cfg.nope else cfg.rope_theta, scale=cfg.attention_multiplier,
-        cache=cache, cache_pos=cache_pos, layer=layer, kv_order=SEQ_MINOR,
-        use_kernels=use_kernels,
+        cache=cache, cache_pos=cache_pos, layer=layer, use_kernels=use_kernels,
     )
     return _gh_mlp(lp, x + h * cfg.residual_multiplier, cfg), kv
 
@@ -411,11 +410,13 @@ def _gh_prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=Fals
             h = _put(h, m, ms.h)
         return x, (conv, h)
 
+    order = kv_order_for(cfg.hd)
+
     def attn_fn(lp, x, st, g):
         k, v = st
         x, kv = _gh_attn(lp, x, cfg, positions=positions, use_kernels=use_kernels)
         with jax.named_scope("kv_write"):
-            k, v = _put(k, g, kv.k.transpose(SEQ_MINOR)), _put(v, g, kv.v.transpose(SEQ_MINOR))
+            k, v = _put(k, g, kv.k.transpose(order)), _put(v, g, kv.v.transpose(order))
         return x, (k, v)
 
     x, (conv, h), (k, v) = _gh_layers(params, x, (c["conv"], c["h"]), (c["k"], c["v"]),
@@ -459,7 +460,7 @@ def _gh_decode(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
 def _gh_init_cache(cfg: ModelConfig, B: int, S_max: int):
     G = _pattern(cfg)[0]
     mc = empty_mamba_cache(cfg, B, dtype_of(cfg))
-    kv = (G, B, cfg.n_kv_heads, cfg.hd, S_max)          # SEQ_MINOR
+    kv = kv_stack_shape(G, B, S_max, cfg.n_kv_heads, cfg.hd)
     return {
         "conv": jnp.zeros((cfg.n_layers - G,) + mc.conv.swapaxes(0, 1).shape, mc.conv.dtype),
         "h": jnp.zeros((cfg.n_layers - G,) + mc.h.shape, mc.h.dtype),

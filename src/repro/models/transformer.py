@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, ShapeSpec
-from .attention import KVCache, attention, attn_init, seq_major
+from .attention import KVCache, attention, attn_init, kv_order_for, kv_stack_shape
 from .common import Model, remat_wrap, stack_init, token_specs
 from .layers import (
     cross_entropy_loss,
@@ -189,12 +189,13 @@ def _forward(
         )
 
     layer_fn = remat_wrap(layer_fn, remat)
+    order = kv_order_for(cfg.hd)
 
     def body(carry, lp):
         x, aux = carry
         x, kv, a = layer_fn(lp, x)
-        # the cache is stacked in the decode loop's (S, K, B, hd) order
-        kv = KVCache(seq_major(kv.k), seq_major(kv.v)) if want_cache else None
+        # the cache is stacked in the order the decode loop reads
+        kv = KVCache(kv.k.transpose(order), kv.v.transpose(order)) if want_cache else None
         return (x, aux + a), kv
 
     with jax.named_scope("layers"):
@@ -244,7 +245,9 @@ def loss_fn(params, batch, cfg: ModelConfig, *, remat=None, use_kernels=False):
 
 
 def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
-    """Run the prompt, return (last-position logits, decode cache)."""
+    """Run the prompt, return (last-position logits, decode cache). The
+    uniform stack's cache holds k/v as (L, ...) stacks in
+    ``kv_order_for(hd)``, padded to ``S_max`` positions."""
     x, n_prefix = _embed_inputs(params, cfg, batch)
     B, S = x.shape[0], x.shape[1]
     dtype = dtype_of(cfg)
@@ -280,9 +283,11 @@ def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
             "pos": jnp.int32(S),
         }
     else:
-        def grow(a):                            # (L, S, K, B, hd)
+        seq = 1 + kv_order_for(cfg.hd).index(1)  # the stacks' sequence axis
+
+        def grow(a):
             pad = [(0, 0)] * a.ndim
-            pad[1] = (0, S_max - S)
+            pad[seq] = (0, S_max - S)
             return jnp.pad(a, pad)
         cache = {"k": grow(kvs.k), "v": grow(kvs.v), "pos": jnp.int32(S)}
     return logits, cache
@@ -358,6 +363,7 @@ def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
 
 
 def init_cache(cfg: ModelConfig, B: int, S_max: int):
+    """An empty decode cache, laid out as ``prefill`` returns it."""
     dtype = dtype_of(cfg)
     K, hd = cfg.n_kv_heads, cfg.hd
     if cfg.local_global_ratio:
@@ -372,10 +378,10 @@ def init_cache(cfg: ModelConfig, B: int, S_max: int):
             "gv": jnp.zeros((G, B, S_max, K, hd), dtype),
             "pos": jnp.int32(0),
         }
-    L = cfg.n_layers
+    stack = kv_stack_shape(cfg.n_layers, B, S_max, K, hd)
     return {
-        "k": jnp.zeros((L, S_max, K, B, hd), dtype),
-        "v": jnp.zeros((L, S_max, K, B, hd), dtype),
+        "k": jnp.zeros(stack, dtype),
+        "v": jnp.zeros(stack, dtype),
         "pos": jnp.int32(0),
     }
 

@@ -18,6 +18,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..models.attention import kv_order_for
+
 MODEL = "model"
 
 
@@ -195,14 +197,16 @@ def batch_shardings(mesh: Mesh, batch_like):
     return jax.tree_util.tree_map_with_path(one, batch_like)
 
 
-# cache leaf name -> (batch_dim_index_from_end, seq_dim_index_from_end) hints
 def cache_shardings(mesh: Mesh, cache_like, cfg):
     """Decode caches: batch over dp where divisible; KV sequence over "model"
     (flash-decode style context parallelism — head-count agnostic); SSM/mLSTM
     states shard heads or channels over "model"."""
     dp = dp_axes(mesh)
-    # the transformer's uniform layer stack: k/v are (L, S, K, B, hd)
+    # the transformer's uniform layer stack and granite-H's attention layers
+    # keep k/v as (L, ...) stacks in kv_order_for(hd), a permutation of
+    # (B, S, K, hd): axis a of that lies at 1 + order.index(a)
     uniform_stack = cfg.family in ("dense", "moe", "vlm") and not cfg.local_global_ratio
+    at = [1 + i for i in (kv_order_for(cfg.hd).index(a) for a in range(4))]
 
     def one(path, leaf):
         name = _path_str(path)
@@ -211,16 +215,17 @@ def cache_shardings(mesh: Mesh, cache_like, cfg):
         if leaf.ndim == 0:
             return NamedSharding(mesh, P())
         if cfg.layer_types and name in ("k", "v", "h", "conv"):
-            # granite-h: k/v (L, B, K, hd, S) and states (L, B, H, P, N) shard
-            # batch over dp and heads over model; conv windows (L, W-1, B, Ch)
+            # granite-h: k/v shard batch over dp and heads over model, as do
+            # the states (L, B, H, P, N); conv windows (L, W-1, B, Ch)
             if name == "conv":
                 spec[-2] = dp
-            else:
+            elif name == "h":
                 spec[-4], spec[-3] = dp, MODEL
+            else:
+                spec[at[0]], spec[at[2]] = dp, MODEL
             return NamedSharding(mesh, _sanitize(mesh, spec, shape))
         if uniform_stack and name in ("k", "v"):
-            spec[-2] = dp
-            spec[-4] = MODEL
+            spec[at[0]], spec[at[1]] = dp, MODEL
             return NamedSharding(mesh, _sanitize(mesh, spec, shape))
         # mlstm matrix memory (..., B, H, dh, dh): BATCH-LOCAL (dp only).
         # Any model-axis sharding here loses: GSPMD cannot reshard between

@@ -92,13 +92,13 @@ def test_ssd_intra_chunk_compiles_zamba2(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_decode_step_keeps_cache_in_place(one_chip):
-    """phi4-mini widths, 2 layers, B=8, a 256-slot cache: the decode step
-    needs no temporary as large as one layer's K slice, and copies no whole
-    cache stack."""
+@pytest.mark.parametrize("B, S_max", [(8, 256), (2, 256)])
+def test_decode_step_keeps_cache_in_place(one_chip, B, S_max):
+    """phi4-mini widths, 2 layers, a batch of 8 and of 2 (phi4-longdoc's):
+    the decode step needs no temporary as large as one layer's K slice, and
+    copies no whole cache stack."""
     cfg = dataclasses.replace(PHI4, n_layers=2)
     model = build_model(cfg)
-    B, S_max = 8, 256
     params_like = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     cache_like = jax.eval_shape(lambda: model.init_cache(B, S_max))
     tok_like = {"token": jax.ShapeDtypeStruct((B,), jnp.int32)}
@@ -111,7 +111,8 @@ def test_decode_step_keeps_cache_in_place(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < layer_k_bytes
     stack = "bf16[" + ",".join(map(str, cache_like["k"].shape)) + "]"
     # copy ops and copy fusions of a whole stack: a scan over the stacks as
-    # xs and ys makes four a step, restacking the ys and copying them out
+    # xs and ys makes four a step, restacking the ys and copying them out;
+    # a loop that wants the stacks in another layout copies them in and out
     copies = re.findall(r"%(copy[\w.\-]*) = " + re.escape(stack), compiled.as_text())
     assert not copies, copies
 
@@ -119,9 +120,10 @@ def test_decode_step_keeps_cache_in_place(one_chip):
 def test_granite_h_serve_steps_fit_one_chip(one_chip):
     """granite-4.0-h-micro at full size, at the benchmark cell's shapes (24
     sequences, prompts of 4096, up to 512 new tokens): prefill and decode
-    each fit the chip's 15.75 GB, and the decode step's temporaries are
-    smaller than one Mamba layer's state slice, so neither the SSM state nor
-    the KV stacks are copied."""
+    each fit the chip's 15.75 GB, the KV stacks of its heads of 64 are
+    SEQ_MINOR, and the decode step's temporaries are smaller than one Mamba
+    layer's state slice, so neither the SSM state nor the KV stacks are
+    copied."""
     cfg, B, P = GRANITE_H, 24, 4096
     S_max = cache_len(P + 512)
     model = build_model(cfg)
@@ -129,6 +131,7 @@ def test_granite_h_serve_steps_fit_one_chip(one_chip):
     batch_like = {"tokens": jax.ShapeDtypeStruct((B, P), jnp.int32)}
     cache_like = jax.eval_shape(lambda p, b: model.prefill(p, b, S_max), params_like,
                                 batch_like)[1]
+    assert cache_like["k"].shape == (4, B, cfg.n_kv_heads, cfg.hd, S_max)  # SEQ_MINOR
     tok_like = {"token": jax.ShapeDtypeStruct((B,), jnp.int32)}
     mesh = Mesh(np.array([*one_chip.device_set]).reshape(1, 1), ("data", "model"),
                 axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -88,9 +88,12 @@ def test_decode_step_sharded_cache():
 
 
 def test_cache_shardings_follow_the_stack_layout():
-    """The uniform stack's (L, S, K, B, hd) cache shards batch over data and
-    sequence over model; gemma3's (..., B, S, K, hd) caches keep theirs."""
+    """The uniform stack's cache shards batch over data and sequence over
+    model wherever kv_order_for(hd) puts them: (L, B, K, hd, S) for the smoke
+    configs' heads of 32, (L, B, K, S, hd) for heads of 128; gemma3's
+    (..., B, S, K, hd) caches keep theirs."""
     _run("""
+        import dataclasses
         import jax
         from jax.sharding import PartitionSpec as P
         from repro.configs import get_smoke
@@ -99,17 +102,19 @@ def test_cache_shardings_follow_the_stack_layout():
         from repro.launch.mesh import make_host_mesh
 
         mesh = make_host_mesh(2, 4)
-        for arch, want in [
-            ("qwen3-14b", {"k": P(None, "model", None, "data", None)}),
-            ("internvl2-2b", {"v": P(None, "model", None, "data", None)}),
-            ("gemma3-12b", {"gk": P(None, "data", "model", None, None),
-                            "lk": P(None, None, "data", "model", None, None)}),
+        wide = dataclasses.replace(get_smoke("qwen3-14b"), head_dim=128)
+        for cfg, want in [
+            (get_smoke("qwen3-14b"), {"k": P(None, "data", None, None, "model")}),
+            (get_smoke("internvl2-2b"), {"v": P(None, "data", None, None, "model")}),
+            (wide, {"k": P(None, "data", None, "model", None)}),
+            (get_smoke("gemma3-12b"), {"gk": P(None, "data", "model", None, None),
+                                       "lk": P(None, None, "data", "model", None, None)}),
         ]:
-            model = build_model(get_smoke(arch))
+            model = build_model(cfg)
             cache = jax.eval_shape(lambda: model.init_cache(8, 64))
             sh = cache_shardings(mesh, cache, model.cfg)
             for name, spec in want.items():
-                assert sh[name].spec == spec, (arch, name, sh[name].spec)
+                assert sh[name].spec == spec, (cfg.name, name, sh[name].spec)
         print("OK")
     """)
 

@@ -11,7 +11,16 @@ import pytest
 
 from repro.configs import ARCH_IDS, get_config, get_smoke, shapes_for
 from repro.models import build_model
-from repro.models.attention import blockwise_sdpa, sdpa
+from repro.models import transformer
+from repro.models.attention import (
+    HEAD_ROWS,
+    SEQ_MINOR,
+    KVCache,
+    blockwise_sdpa,
+    kv_order_for,
+    kv_stack_shape,
+    sdpa,
+)
 from repro.runtime import (
     RuntimeConfig,
     make_train_state,
@@ -85,7 +94,8 @@ def test_decode_matches_full_forward(arch):
     assert err / scale < 2e-2, (arch, err, scale)
 
 
-# one (L, S_max, K, B, hd) stack per k and v, carried through the decode scan
+# one (L, ...) stack per k and v in kv_order_for(hd), carried through the
+# decode scan
 UNIFORM_STACK = [a for a in ARCH_IDS
                  if get_smoke(a).family in ("dense", "moe", "vlm")
                  and not get_smoke(a).local_global_ratio]
@@ -110,19 +120,70 @@ def test_decode_cache_matches_prefill(arch):
     _, full = model.prefill(params, dict(batch, tokens=toks), S_max)
     n = n_pref + S + 4
     assert int(cache["pos"]) == int(full["pos"]) == n
+    back = _stack_to_rows(cfg.hd)
     for name in ("k", "v"):
-        got, want = np.asarray(cache[name]), np.asarray(full[name])
-        assert got.shape == (cfg.n_layers, S_max, cfg.n_kv_heads, B, cfg.hd)
-        assert got.shape == model.init_cache(B, S_max)[name].shape
-        err = np.max(np.abs(got[:, :n] - want[:, :n]))
-        assert err / np.max(np.abs(want[:, :n])) < 2e-2, (arch, name, err)
-        assert not np.any(got[:, n:]), (arch, name)
+        assert cache[name].shape == model.init_cache(B, S_max)[name].shape
+        got = np.asarray(cache[name]).transpose(back)
+        want = np.asarray(full[name]).transpose(back)
+        assert got.shape == (cfg.n_layers, B, S_max, cfg.n_kv_heads, cfg.hd)
+        err = np.max(np.abs(got[:, :, :n] - want[:, :, :n]))
+        assert err / np.max(np.abs(want[:, :, :n])) < 2e-2, (arch, name, err)
+        assert not np.any(got[:, :, n:]), (arch, name)
 
 
-def test_decode_kernel_path_matches_jnp():
+def _stack_to_rows(hd):
+    """The permutation that views (L, ...) stacks in ``kv_order_for(hd)`` as
+    (L, B, S, K, hd)."""
+    order = kv_order_for(hd)
+    return (0,) + tuple(1 + order.index(a) for a in range(4))
+
+
+def test_kv_order_follows_the_head_width():
+    """Heads that fill the 128 lanes keep rows of hd per (b, k); narrower
+    heads put the sequence on the lanes."""
+    assert kv_order_for(128) == kv_order_for(256) == HEAD_ROWS
+    assert kv_order_for(64) == kv_order_for(32) == SEQ_MINOR
+    assert kv_stack_shape(32, 24, 1024, 8, 128) == (32, 24, 8, 1024, 128)
+    assert kv_stack_shape(4, 24, 4608, 8, 64) == (4, 24, 8, 64, 4608)
+
+
+def test_head_rows_decode_matches_plain_sdpa():
+    """Heads of 128 in phi4's 3:1 grouping, 2 layers: four decode steps over
+    the HEAD_ROWS stacks give the logits of a plain decode that keeps each
+    layer's cache as (B, S, K, hd) and attends with ``sdpa``."""
+    cfg = dataclasses.replace(get_smoke("phi4-mini-3.8b"), n_layers=2, n_heads=6,
+                              n_kv_heads=2, head_dim=128)
+    assert kv_order_for(cfg.hd) == HEAD_ROWS
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    S_max = S + 8
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, S + 4), 0, cfg.vocab_size)
+    _, cache = model.prefill(params, {"tokens": toks[:, :S]}, S_max)
+    assert cache["k"].shape == (2, B, cfg.n_kv_heads, S_max, cfg.hd)
+    back = _stack_to_rows(cfg.hd)
+    ks = list(cache["k"].transpose(back))
+    vs = list(cache["v"].transpose(back))
+    for t in range(4):
+        tok = toks[:, S + t]
+        logits, cache = model.decode_step(params, cache, {"token": tok})
+        pos = jnp.int32(S + t)
+        x = transformer.embed(params["embed"], tok[:, None])
+        for i in range(cfg.n_layers):
+            lp = jax.tree.map(lambda a: a[i], params["layers"])
+            x, kv, _ = transformer._layer_apply(
+                lp, x, cfg, positions=pos[None], theta=cfg.rope_theta, window=None,
+                cache=KVCache(ks[i], vs[i]), cache_pos=pos)
+            ks[i], vs[i] = kv.k, kv.v
+        want = transformer._logits(params, cfg, transformer._final_norm(params, cfg, x)[:, 0])
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("hd", [32, 128])
+def test_decode_kernel_path_matches_jnp(hd):
     """The Pallas decode kernel (interpreted here) reads the layer's slice of
-    the stacks as the jnp attention does."""
-    cfg = get_smoke("phi4-mini-3.8b")
+    the stacks as the jnp attention does, in either order (SEQ_MINOR for
+    heads of 32, HEAD_ROWS for heads of 128)."""
+    cfg = dataclasses.replace(get_smoke("phi4-mini-3.8b"), head_dim=hd)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     S_max = S + 32

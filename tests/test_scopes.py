@@ -83,13 +83,15 @@ def test_scopes_change_nothing_but_metadata(texts, monkeypatch):
     bare = _compiled_texts()
     for step, text in texts.items():
         assert "metadata=" in text
-        assert _without_metadata(bare[step]) == _without_metadata(text), step
+        assert _renumbered(_without_metadata(bare[step])) == \
+            _renumbered(_without_metadata(text)), step
         assert "/layers/" not in bare[step]
 
 
 def _renumbered(text: str) -> str:
     """Instruction names numbered in their order of appearance: the compiler
-    numbers the hybrid's transposes in the order it meets their scopes."""
+    numbers the transposes of a SEQ_MINOR cache in the order it meets their
+    scopes."""
     names = {}
     return re.sub(r"%([\w\-]+?)\.(\d+)\b",
                   lambda m: "%" + m.group(1) + "." + names.setdefault(m.group(0), str(len(names))),
