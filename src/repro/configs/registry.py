@@ -12,7 +12,6 @@ _MODULES = {
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
     "phi4-mini-3.8b": "phi4_mini_3_8b",
-    "gemma3-12b": "gemma3_12b",
     "qwen2.5-32b": "qwen2_5_32b",
     "qwen3-14b": "qwen3_14b",
     "xlstm-1.3b": "xlstm_1_3b",

@@ -39,7 +39,6 @@ def model_config(arch: str, *, full: bool, layers: int | None = None) -> ModelCo
     if layers is None or layers == cfg.n_layers:
         return cfg
     period = max(1, cfg.shared_attn_every, cfg.slstm_period,
-                 cfg.local_global_ratio + 1 if cfg.local_global_ratio else 1,
                  cfg.n_layers // cfg.layer_types.count("attention") if cfg.layer_types else 1)
     if not 0 < layers <= cfg.n_layers or layers % period:
         raise ValueError(f"{cfg.name}: cannot cut {cfg.n_layers} layers to {layers} "

@@ -1,4 +1,12 @@
-"""GQA attention with causal/sliding-window masks and a decode KV cache.
+"""GQA attention with causal/sliding-window masks, and the decode KV cache.
+
+The decode cache has one design, for every family: each k and v is one
+(L, ...) stack of all attention layers (or sites) in ``kv_order_for(hd)``,
+which the decode loop carries through its layer scan and ``attention(...,
+layer=...)`` writes and reads in place. This module owns its layout: the
+order, the stacks' shape (``kv_stack_shape``) and axes (``kv_axes``), how a
+prefill builds them (``to_stack_order``, ``pad_stacks``) and how a decode
+step writes one position (``_write_position``).
 
 The compute-heavy paths dispatch to Pallas kernels (``repro.kernels.ops``)
 when ``use_kernels`` is on; the pure-jnp path here is the oracle and the
@@ -44,15 +52,51 @@ def kv_stack_shape(n: int, B: int, S_max: int, K: int, hd: int) -> tuple:
     return (n,) + tuple((B, S_max, K, hd)[a] for a in kv_order_for(hd))
 
 
+class KVAxes(NamedTuple):
+    """Where an (L, ...) stack of heads of one width keeps its batch,
+    sequence and head axes."""
+
+    batch: int
+    seq: int
+    heads: int
+
+
+def kv_axes(hd: int) -> KVAxes:
+    """The axes of an (L, ...) stack in ``kv_order_for(hd)``."""
+    order = kv_order_for(hd)
+    return KVAxes(*(1 + order.index(a) for a in range(3)))
+
+
 class KVCache(NamedTuple):
-    """Decode cache keys and values. Per layer, k/v: (B, S_max, K, hd). The
-    transformer's uniform layer stack and granite-H's attention layers keep
-    all layers in one pair of (L, ...) stacks in ``kv_order_for(hd)``
-    instead, which the decode loop reads in place
-    (``attention(..., layer=...)``)."""
+    """Keys and values. A prefill's ``attention`` returns one layer's, each
+    (B, S, K, hd); the decode cache holds every layer's as a pair of (L, ...)
+    stacks in ``kv_order_for(hd)`` (``to_stack_order``, ``pad_stacks``),
+    which the decode loop carries and ``attention(..., layer=...)`` reads and
+    writes in place."""
 
     k: jnp.ndarray
     v: jnp.ndarray
+
+
+def to_stack_order(kv: KVCache) -> KVCache:
+    """One layer's keys and values, (B, S, K, hd) each, in ``kv_order_for(hd)``:
+    what a prefill's layer scan emits, so that it returns (L, ...) stacks."""
+    order = kv_order_for(kv.k.shape[-1])
+    return KVCache(kv.k.transpose(order), kv.v.transpose(order))
+
+
+def pad_stacks(kv: KVCache, hd: int, S_max: int) -> KVCache:
+    """(L, ...) stacks of heads of ``hd`` in ``kv_order_for(hd)``, the
+    sequence padded to ``S_max`` positions: the decode cache a prefill
+    returns."""
+    seq = kv_axes(hd).seq
+
+    def grow(a):
+        pad = [(0, 0)] * a.ndim
+        pad[seq] = (0, S_max - a.shape[seq])
+        return jnp.pad(a, pad)
+
+    return KVCache(grow(kv.k), grow(kv.v))
 
 
 def attn_init(rng, cfg: ModelConfig, *, dtype=jnp.float32):
@@ -78,19 +122,12 @@ def _mask(
     window: Optional[int],
     q_offset,
     kv_len=None,
-    kv_positions=None,
 ):
     """(S_q, S_k) additive mask. ``q_offset``: absolute position of query row 0
-    (static int or traced scalar). ``kv_len``: valid prefix of the key axis.
-    ``kv_positions``: (S_k,) absolute positions of the keys (ring caches);
-    negative entries mean 'empty slot'."""
+    (static int or traced scalar). ``kv_len``: valid prefix of the key axis."""
     rows = jnp.arange(S_q)[:, None] + q_offset
-    if kv_positions is not None:
-        cols = kv_positions[None, :]
-        ok = cols >= 0
-    else:
-        cols = jnp.arange(S_k)[None, :]
-        ok = jnp.ones((S_q, S_k), dtype=bool)
+    cols = jnp.arange(S_k)[None, :]
+    ok = jnp.ones((S_q, S_k), dtype=bool)
     if causal:
         ok &= cols <= rows
     if window is not None:
@@ -109,7 +146,6 @@ def sdpa(
     window: Optional[int] = None,
     q_offset=0,
     kv_len=None,
-    kv_positions=None,
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Reference GQA attention. q: (B,S,H,hd); k/v: (B,T,K,hd); H % K == 0.
@@ -121,8 +157,7 @@ def sdpa(
     scale = hd ** -0.5 if scale is None else scale
     logits = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32) * scale
     logits = logits + _mask(
-        S, T, causal=causal, window=window, q_offset=q_offset,
-        kv_len=kv_len, kv_positions=kv_positions,
+        S, T, causal=causal, window=window, q_offset=q_offset, kv_len=kv_len,
     )
     w = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", w, v)
@@ -215,8 +250,6 @@ def attention(
     window: Optional[int] = None,
     cache: Optional[KVCache] = None,
     cache_pos=None,
-    cache_write_pos=None,
-    kv_positions=None,
     kv_override: Optional[tuple] = None,
     layer=None,
     scale: Optional[float] = None,
@@ -227,12 +260,11 @@ def attention(
     Modes:
       * train/prefill: ``cache is None`` -> attends within x; returns
         (out, KVCache(k, v)) so prefill can keep the cache.
-      * decode: ``cache`` given, x is (B, 1, d); keys/values are inserted at
-        ``cache_pos`` and attention runs over the cache prefix.
-        With ``layer`` (a traced index), ``cache`` holds every layer's
-        stacks in ``kv_order_for(hd)``: only the new token's row of that
-        layer is written, the layer's slice is read in place, and the stacks
-        are returned.
+      * decode: ``cache`` holds every layer's (L, ...) stacks in
+        ``kv_order_for(hd)`` and ``layer`` (a traced index) names this one;
+        x is (B, 1, d). Only the new token's position ``cache_pos`` of the
+        layer's slice is written, the slice is read in place over the valid
+        prefix (and ``window``), and the stacks are returned.
       * cross-attention: ``kv_override=(k, v)`` skips rope/cache.
 
     ``scale`` multiplies the scores (default 1/sqrt(hd)); ``theta <= 0``
@@ -278,41 +310,21 @@ def attention(
                 out = sdpa(q, k, v, causal=causal, window=window, scale=scale)
         new_cache = KVCache(k, v)
     else:
-        # decode: write k/v at cache_write_pos (ring caches pass pos % W),
-        # attend over the valid region.
-        wp = cache_pos if cache_write_pos is None else cache_write_pos
         kv_order = kv_order_for(hd)
         with jax.named_scope("kv_write"):
-            if layer is None:
-                ck = jax.lax.dynamic_update_slice(cache.k, k, (0, wp, 0, 0))
-                cv = jax.lax.dynamic_update_slice(cache.v, v, (0, wp, 0, 0))
-            else:
-                # one position of the layer's slice changes; the stacks stay
-                # in place through the layer scan
-                ck = _write_position(cache.k, k, layer, wp, kv_order)
-                cv = _write_position(cache.v, v, layer, wp, kv_order)
+            # one position of the layer's slice changes; the stacks stay in
+            # place through the layer scan
+            ck = _write_position(cache.k, k, layer, cache_pos, kv_order)
+            cv = _write_position(cache.v, v, layer, cache_pos, kv_order)
         with jax.named_scope("attend"):
-            if layer is None:
-                kc, vc = ck, cv
-            else:
-                # the layer's slice as (B, S_max, K, hd); the compiler folds
-                # the transpose into the attention's reads (re-laying the
-                # slice out as (B*K, S_max, hd) matrices first is slower)
-                back = tuple(kv_order.index(a) for a in range(4))
-                kc = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
-                vc = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
-                kc, vc = kc.transpose(back), vc.transpose(back)
-            if kv_positions is not None:
-                # ring cache: validity comes from the positions array
-                out = sdpa(
-                    q, kc, vc,
-                    causal=True,
-                    window=window,
-                    q_offset=cache_pos,
-                    kv_positions=kv_positions,
-                    scale=scale,
-                )
-            elif use_kernels:
+            # the layer's slice as (B, S_max, K, hd); the compiler folds the
+            # transpose into the attention's reads (re-laying the slice out
+            # as (B*K, S_max, hd) matrices first is slower)
+            back = tuple(kv_order.index(a) for a in range(4))
+            kc = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
+            vc = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
+            kc, vc = kc.transpose(back), vc.transpose(back)
+            if use_kernels:
                 from ..kernels import ops as kops
                 out = kops.decode_attention(
                     q, kc, vc, kv_len=cache_pos + S, window=window, scale=scale
@@ -340,7 +352,7 @@ def _write_position(stack, new, layer, pos, order):
     ``pos`` is rewritten whole: an update one lane or one sublane wide would
     force the stack into another layout."""
     row = new.transpose(order)[None]
-    ax = 1 + order.index(1)                 # 4: lanes, 3: sublanes
+    ax = kv_axes(new.shape[-1]).seq         # 4: lanes, 3: sublanes
     S = stack.shape[ax]
     blk = LANES if ax == 4 else SUBLANES
     blk = blk if S % blk == 0 else S
@@ -349,8 +361,3 @@ def _write_position(stack, new, layer, pos, order):
     old = jax.lax.dynamic_slice(stack, at, size)
     hit = (jnp.arange(blk) == pos % blk).reshape((blk,) + (1,) * (4 - ax))
     return jax.lax.dynamic_update_slice(stack, jnp.where(hit, row, old), at)
-
-
-def empty_cache(cfg: ModelConfig, B: int, S_max: int, dtype) -> KVCache:
-    shape = (B, S_max, cfg.n_kv_heads, cfg.hd)
-    return KVCache(jnp.zeros(shape, dtype=dtype), jnp.zeros(shape, dtype=dtype))

@@ -1,9 +1,14 @@
 """Whisper-style encoder-decoder. The conv/mel frontend is a STUB per the
 brief: ``input_specs`` provides precomputed frame embeddings (B, enc_seq, d).
 
-Adaptations noted in DESIGN.md: sinusoidal absolute embeddings on the encoder
-(as Whisper), RoPE in the decoder self-attention (instead of Whisper's learned
-448-entry table, which cannot address the assigned 32k/500k decode shapes).
+Adaptations: sinusoidal absolute embeddings on the encoder (as Whisper), RoPE
+in the decoder self-attention (instead of Whisper's learned 448-entry table,
+which cannot address the assigned 32k/500k decode shapes).
+
+The decode cache keeps the decoder's self-attention keys and values as
+(L, ...) stacks in ``attention.kv_order_for(hd)``, carried through the layer
+scan and written in place, and each layer's cross-attention keys and values
+over the encoder output (``ck``/``cv``, read only) as scanned inputs.
 """
 
 from __future__ import annotations
@@ -14,7 +19,14 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, ShapeSpec
-from .attention import KVCache, attention, attn_init
+from .attention import (
+    KVCache,
+    attention,
+    attn_init,
+    kv_stack_shape,
+    pad_stacks,
+    to_stack_order,
+)
 from .common import Model, remat_wrap, stack_init, token_specs
 from .layers import (
     cross_entropy_loss,
@@ -97,12 +109,12 @@ def encode(params, cfg: ModelConfig, frames, *, remat=None):
 
 def _dec_layer(
     lp, x, cfg, *, positions, enc_kv=None, enc_out=None,
-    cache=None, cache_pos=None,
+    cache=None, cache_pos=None, layer=None,
 ):
     h, kv = attention(
         lp["self_attn"], rmsnorm(lp["ln1"], x, cfg.norm_eps), cfg,
         positions=positions, theta=cfg.rope_theta,
-        cache=cache, cache_pos=cache_pos,
+        cache=cache, cache_pos=cache_pos, layer=layer,
     )
     x = x + h
     xc = rmsnorm(lp["lnc"], x, cfg.norm_eps)
@@ -146,28 +158,19 @@ def loss_fn(params, batch, cfg: ModelConfig, *, remat=None, use_kernels=False):
 def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
     enc_out = encode(params, cfg, batch["frames"])
     x = embed(params["embed"], batch["tokens"])
-    B, S = x.shape[:2]
+    S = x.shape[1]
     positions = jnp.arange(S)
 
     def body(x, lp):
         x, kv, enc_kv = _dec_layer(lp, x, cfg, positions=positions, enc_out=enc_out)
-        return x, (kv, enc_kv)
+        return x, (to_stack_order(kv), enc_kv)
 
     x, (kvs, enc_kvs) = jax.lax.scan(body, x, params["dec_layers"])
     h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = unembed(params["unembed"], h[:, -1])
-
-    def grow(a):
-        pad = [(0, 0)] * a.ndim
-        pad[-3] = (0, S_max - S)
-        return jnp.pad(a, pad)
-
-    cache = {
-        "k": grow(kvs.k), "v": grow(kvs.v),
-        "ck": enc_kvs[0], "cv": enc_kvs[1],
-        "pos": jnp.int32(S),
-    }
-    return logits, cache
+    k, v = pad_stacks(kvs, cfg.hd, S_max)
+    return logits, {"k": k, "v": v, "ck": enc_kvs[0], "cv": enc_kvs[1],
+                    "pos": jnp.int32(S)}
 
 
 def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
@@ -175,30 +178,32 @@ def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
     pos = cache["pos"]
     positions = pos[None]
 
-    def body(x, inp):
-        lp, k1, v1, ck, cv = inp
+    # the self-attention stacks ride in the carry, updated in place
+    def body(carry, inp):
+        x, k, v = carry
+        lp, ck, cv, layer = inp
         x, kv, _ = _dec_layer(
             lp, x, cfg, positions=positions, enc_kv=(ck, cv),
-            cache=KVCache(k1, v1), cache_pos=pos,
+            cache=KVCache(k, v), cache_pos=pos, layer=layer,
         )
-        return x, kv
+        return (x, kv.k, kv.v), None
 
-    x, kvs = jax.lax.scan(
-        body, x,
-        (params["dec_layers"], cache["k"], cache["v"], cache["ck"], cache["cv"]),
+    (x, k, v), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["dec_layers"], cache["ck"], cache["cv"], jnp.arange(cfg.n_layers)),
     )
     h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = unembed(params["unembed"], h[:, 0])
-    new_cache = dict(cache, k=kvs.k, v=kvs.v, pos=pos + 1)
-    return logits, new_cache
+    return logits, dict(cache, k=k, v=v, pos=pos + 1)
 
 
 def init_cache(cfg: ModelConfig, B: int, S_max: int):
     dtype = dtype_of(cfg)
     L, K, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
+    stack = kv_stack_shape(L, B, S_max, K, hd)
     return {
-        "k": jnp.zeros((L, B, S_max, K, hd), dtype),
-        "v": jnp.zeros((L, B, S_max, K, hd), dtype),
+        "k": jnp.zeros(stack, dtype),
+        "v": jnp.zeros(stack, dtype),
         "ck": jnp.zeros((L, B, cfg.encoder_seq, K, hd), dtype),
         "cv": jnp.zeros((L, B, cfg.encoder_seq, K, hd), dtype),
         "pos": jnp.int32(0),

@@ -1,12 +1,16 @@
-"""Mamba2 hybrids, sharing one mixer (``mamba2.mamba_forward``).
+"""Mamba2 hybrids, sharing one mixer (``mamba2.mamba_forward``) and one
+decode KV cache design: (L, ...) key and value stacks in
+``attention.kv_order_for(hd)``, one entry per attention site, carried
+through the layer scans and written in place (``attention(..., layer=...)``).
 
 Zamba2-style (``shared_attn_every``): Mamba2 backbone with a single
 weight-shared attention+MLP block applied after every ``shared_attn_every``
-mamba layers. Simplification vs. the released Zamba2 (noted in DESIGN.md):
-the shared block consumes the residual stream directly (no concat with the
-original embedding, no per-invocation LoRA). Structure (mamba backbone +
-periodically-invoked tied attention with its own KV cache per invocation
-site) is preserved.
+mamba layers. Unlike the released Zamba2, the shared block consumes the
+residual stream directly (no concat with the original embedding, no
+per-invocation LoRA). Each invocation site keeps its own K/V: entry g of the
+``k``/``v`` stacks, which the group scan carries and indexes by group. The
+mamba layers' conv windows and states pass through the nested scans as
+inputs and outputs.
 
 Granite-4.0-H-style (``layer_types``, HF ``GraniteMoeHybrid`` without
 experts): every layer is a pre-norm mixer, Mamba2 or GQA attention (scale
@@ -31,7 +35,14 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, ShapeSpec
-from .attention import KVCache, attention, attn_init, kv_order_for, kv_stack_shape
+from .attention import (
+    KVCache,
+    attention,
+    attn_init,
+    kv_stack_shape,
+    pad_stacks,
+    to_stack_order,
+)
 from .common import Model, remat_wrap, stack_init, token_specs
 from .layers import (
     cross_entropy_loss,
@@ -69,11 +80,11 @@ def _mamba_layer(lp, x, cfg, cache=None, use_kernels=False):
     return x + h, new_cache
 
 
-def _shared_apply(sp, x, cfg, *, positions, cache=None, cache_pos=None):
+def _shared_apply(sp, x, cfg, *, positions, cache=None, cache_pos=None, layer=None):
     h, kv = attention(
         sp["attn"], rmsnorm(sp["ln1"], x, cfg.norm_eps), cfg,
         positions=positions, theta=cfg.rope_theta,
-        cache=cache, cache_pos=cache_pos,
+        cache=cache, cache_pos=cache_pos, layer=layer,
     )
     x = x + h
     x = x + swiglu(sp["mlp"], rmsnorm(sp["ln2"], x, cfg.norm_eps))
@@ -106,36 +117,30 @@ def init(rng, cfg: ModelConfig):
     return params
 
 
-def _forward(params, cfg, x, positions, *, want_cache: bool, remat=None,
-             use_kernels=False):
+def _forward(params, cfg, x, positions, *, remat=None, use_kernels=False):
+    """The hidden states of a whole sequence, with no cache."""
     shared = params["shared"]
     m_layer = remat_wrap(
         functools.partial(_mamba_layer, cfg=cfg, use_kernels=use_kernels), remat
     )
 
+    def inner(xc, lp):
+        return m_layer(lp, xc)[0], None
+
     def group(x, gp):
-        def inner(xc, lp):
-            xc, _ = m_layer(lp, xc)
-            return xc, None
-
         x, _ = jax.lax.scan(inner, x, gp)
-        x, kv = _shared_apply(shared, x, cfg, positions=positions)
-        return x, kv
+        return _shared_apply(shared, x, cfg, positions=positions)[0], None
 
-    x, skv = jax.lax.scan(group, x, params["mamba_groups"])
+    x, _ = jax.lax.scan(group, x, params["mamba_groups"])
     if "mamba_tail" in params:
-        def inner(xc, lp):
-            xc, _ = m_layer(lp, xc)
-            return xc, None
         x, _ = jax.lax.scan(inner, x, params["mamba_tail"])
-    return x, (skv if want_cache else None)
+    return x
 
 
 def loss_fn(params, batch, cfg: ModelConfig, *, remat=None, use_kernels=False):
     x = embed(params["embed"], batch["tokens"])
     S = x.shape[1]
-    h, _ = _forward(params, cfg, x, jnp.arange(S), want_cache=False, remat=remat,
-                    use_kernels=use_kernels)
+    h = _forward(params, cfg, x, jnp.arange(S), remat=remat, use_kernels=use_kernels)
     h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
     logits = unembed(params.get("unembed", params["embed"]), h)
     ce = cross_entropy_loss(logits, batch["labels"])
@@ -144,9 +149,10 @@ def loss_fn(params, batch, cfg: ModelConfig, *, remat=None, use_kernels=False):
 
 def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
     """Run the prompt, keeping each mamba layer's final conv window and state
-    and the shared block's K/V at every invocation site."""
+    and the shared block's K/V at every invocation site, as (ng, ...) stacks
+    padded to ``S_max`` positions."""
     x = embed(params["embed"], batch["tokens"])
-    B, S = x.shape[:2]
+    S = x.shape[1]
     positions = jnp.arange(S)
     shared = params["shared"]
 
@@ -156,9 +162,9 @@ def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
     def group(x, gp):
         x, states = jax.lax.scan(inner, x, gp)
         x, kv = _shared_apply(shared, x, cfg, positions=positions)
-        return x, (states, kv)
+        return x, (states, to_stack_order(kv))
 
-    x, (g_states, skv) = jax.lax.scan(group, x, params["mamba_groups"])
+    x, (g_states, kvs) = jax.lax.scan(group, x, params["mamba_groups"])
     t_states = None
     if "mamba_tail" in params:
         x, t_states = jax.lax.scan(inner, x, params["mamba_tail"])
@@ -166,14 +172,9 @@ def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
     h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = unembed(params.get("unembed", params["embed"]), h[:, -1])
 
-    def grow(a):
-        pad = [(0, 0)] * a.ndim
-        pad[-3] = (0, S_max - S)
-        return jnp.pad(a, pad)
-
+    k, v = pad_stacks(kvs, cfg.hd, S_max)
     cache = {
-        "g_conv": g_states.conv, "g_h": g_states.h,
-        "sk": grow(skv.k), "sv": grow(skv.v),
+        "g_conv": g_states.conv, "g_h": g_states.h, "k": k, "v": v,
         "pos": jnp.int32(S),
     }
     if t_states is not None:
@@ -187,35 +188,30 @@ def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
     positions = pos[None]
     shared = params["shared"]
 
-    def group(x, gp):
-        lps, conv, h, k1, v1 = gp
+    def m_step(xc, inp):
+        lp, c, hh = inp
+        return _mamba_layer(lp, xc, cfg, cache=MambaCache(c, hh))
 
-        def inner(xc, inp):
-            lp, c, hh = inp
-            xc, st = _mamba_layer(lp, xc, cfg, cache=MambaCache(c, hh))
-            return xc, st
-
-        x, states = jax.lax.scan(inner, x, (lps, conv, h))
+    # the K/V stacks ride in the group scan's carry, updated in place
+    def group(carry, inp):
+        x, k, v = carry
+        lps, conv, h, g = inp
+        x, states = jax.lax.scan(m_step, x, (lps, conv, h))
         x, kv = _shared_apply(shared, x, cfg, positions=positions,
-                              cache=KVCache(k1, v1), cache_pos=pos)
-        return x, (states, kv)
+                              cache=KVCache(k, v), cache_pos=pos, layer=g)
+        return (x, kv.k, kv.v), states
 
-    x, (g_states, skv) = jax.lax.scan(
-        group, x,
-        (params["mamba_groups"], cache["g_conv"], cache["g_h"],
-         cache["sk"], cache["sv"]),
+    ng = _groups(cfg)[0]
+    (x, k, v), g_states = jax.lax.scan(
+        group, (x, cache["k"], cache["v"]),
+        (params["mamba_groups"], cache["g_conv"], cache["g_h"], jnp.arange(ng)),
     )
     new_cache = {
-        "g_conv": g_states.conv, "g_h": g_states.h,
-        "sk": skv.k, "sv": skv.v, "pos": pos + 1,
+        "g_conv": g_states.conv, "g_h": g_states.h, "k": k, "v": v, "pos": pos + 1,
     }
     if "mamba_tail" in params:
-        def inner(xc, inp):
-            lp, c, hh = inp
-            xc, st = _mamba_layer(lp, xc, cfg, cache=MambaCache(c, hh))
-            return xc, st
         x, t_states = jax.lax.scan(
-            inner, x, (params["mamba_tail"], cache["t_conv"], cache["t_h"])
+            m_step, x, (params["mamba_tail"], cache["t_conv"], cache["t_h"])
         )
         new_cache["t_conv"], new_cache["t_h"] = t_states.conv, t_states.h
 
@@ -235,11 +231,10 @@ def init_cache(cfg: ModelConfig, B: int, S_max: int):
     def rep2(a):
         return jnp.broadcast_to(a, (ng, gs) + a.shape).copy()
 
-    K, hd = cfg.n_kv_heads, cfg.hd
+    kv = kv_stack_shape(ng, B, S_max, cfg.n_kv_heads, cfg.hd)
     cache = {
         "g_conv": rep2(mc.conv), "g_h": rep2(mc.h),
-        "sk": jnp.zeros((ng, B, S_max, K, hd), dtype),
-        "sv": jnp.zeros((ng, B, S_max, K, hd), dtype),
+        "k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
         "pos": jnp.int32(0),
     }
     if tail:
@@ -410,13 +405,12 @@ def _gh_prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=Fals
             h = _put(h, m, ms.h)
         return x, (conv, h)
 
-    order = kv_order_for(cfg.hd)
-
     def attn_fn(lp, x, st, g):
         k, v = st
         x, kv = _gh_attn(lp, x, cfg, positions=positions, use_kernels=use_kernels)
         with jax.named_scope("kv_write"):
-            k, v = _put(k, g, kv.k.transpose(order)), _put(v, g, kv.v.transpose(order))
+            kv = to_stack_order(kv)
+            k, v = _put(k, g, kv.k), _put(v, g, kv.v)
         return x, (k, v)
 
     x, (conv, h), (k, v) = _gh_layers(params, x, (c["conv"], c["h"]), (c["k"], c["v"]),

@@ -1,8 +1,8 @@
-"""Decoder-only transformer LM assembly: dense, MoE, gemma3-style
-local/global block pattern, and VLM (prefix patch embeddings).
+"""Decoder-only transformer LM assembly: dense, MoE, and VLM (prefix patch
+embeddings). Every layer attends alike, within ``sliding_window`` if set.
 
-Layer stacks are scanned (``lax.scan``) over stacked params for compile-time
-O(1) in depth; gemma3 uses a nested scan over (blocks x [R local + 1 global]).
+The layer stack is scanned (``lax.scan``) over stacked params for
+compile-time O(1) in depth.
 
 Train, prefill and decode name their work with ``jax.named_scope`` from one
 vocabulary, which profiler traces carry as each op's ``op_name`` path:
@@ -23,7 +23,14 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, ShapeSpec
-from .attention import KVCache, attention, attn_init, kv_order_for, kv_stack_shape
+from .attention import (
+    KVCache,
+    attention,
+    attn_init,
+    kv_stack_shape,
+    pad_stacks,
+    to_stack_order,
+)
 from .common import Model, remat_wrap, stack_init, token_specs
 from .layers import (
     cross_entropy_loss,
@@ -69,8 +76,6 @@ def _layer_apply(
     window: Optional[int],
     cache: Optional[KVCache] = None,
     cache_pos=None,
-    cache_write_pos=None,
-    kv_positions=None,
     layer=None,
     use_kernels: bool = False,
 ):
@@ -86,8 +91,6 @@ def _layer_apply(
         window=window,
         cache=cache,
         cache_pos=cache_pos,
-        cache_write_pos=cache_write_pos,
-        kv_positions=kv_positions,
         layer=layer,
         use_kernels=use_kernels,
     )
@@ -116,17 +119,7 @@ def init(rng, cfg: ModelConfig):
     if not cfg.tie_embeddings:
         params["unembed"] = embed_init(r_un, cfg.padded_vocab, cfg.d_model, dtype)
     layer_fn = functools.partial(_layer_init, cfg=cfg, dtype=dtype)
-    if cfg.local_global_ratio:
-        R = cfg.local_global_ratio
-        G = cfg.n_layers // (R + 1)
-        rl, rg = jax.random.split(r_layers)
-        local = stack_init(rl, G * R, layer_fn)
-        params["local_layers"] = jax.tree.map(
-            lambda a: a.reshape(G, R, *a.shape[1:]), local
-        )
-        params["global_layers"] = stack_init(rg, G, layer_fn)
-    else:
-        params["layers"] = stack_init(r_layers, cfg.n_layers, layer_fn)
+    params["layers"] = stack_init(r_layers, cfg.n_layers, layer_fn)
     return params
 
 
@@ -143,44 +136,8 @@ def _forward(
     remat: Optional[str] = None,
     use_kernels: bool = False,
 ):
-    """x: (B, S, d) embedded input. Returns (hidden, cache_arrays, aux)."""
-    if cfg.local_global_ratio:
-        R = cfg.local_global_ratio
-        W = cfg.sliding_window
-        g_theta = cfg.global_rope_theta or cfg.rope_theta
-
-        def local_fn(lp, x):
-            return _layer_apply(
-                lp, x, cfg, positions=positions, theta=cfg.rope_theta,
-                window=W, use_kernels=use_kernels,
-            )
-
-        def global_fn(lp, x):
-            return _layer_apply(
-                lp, x, cfg, positions=positions, theta=g_theta,
-                window=None, use_kernels=use_kernels,
-            )
-
-        local_fn = remat_wrap(local_fn, remat)
-        global_fn = remat_wrap(global_fn, remat)
-
-        def block(x, bp):
-            lps, gp = bp
-
-            def inner(xc, lp):
-                xc, kv, _ = local_fn(lp, xc)
-                return xc, kv
-
-            x, lkv = jax.lax.scan(inner, x, lps)
-            x, gkv, _ = global_fn(gp, x)
-            return x, (lkv, gkv)
-
-        with jax.named_scope("layers"):
-            x, (lkvs, gkvs) = jax.lax.scan(
-                block, x, (params["local_layers"], params["global_layers"])
-            )
-        cache = {"local": lkvs, "global": gkvs} if want_cache else None
-        return x, cache, 0.0
+    """x: (B, S, d) embedded input. Returns (hidden, every layer's K/V as
+    (L, ...) stacks in ``kv_order_for(hd)`` if ``want_cache``, aux)."""
 
     def layer_fn(lp, x):
         return _layer_apply(
@@ -189,14 +146,11 @@ def _forward(
         )
 
     layer_fn = remat_wrap(layer_fn, remat)
-    order = kv_order_for(cfg.hd)
 
     def body(carry, lp):
         x, aux = carry
         x, kv, a = layer_fn(lp, x)
-        # the cache is stacked in the order the decode loop reads
-        kv = KVCache(kv.k.transpose(order), kv.v.transpose(order)) if want_cache else None
-        return (x, aux + a), kv
+        return (x, aux + a), (to_stack_order(kv) if want_cache else None)
 
     with jax.named_scope("layers"):
         (x, aux), kvs = jax.lax.scan(body, (x, 0.0), params["layers"])
@@ -246,51 +200,17 @@ def loss_fn(params, batch, cfg: ModelConfig, *, remat=None, use_kernels=False):
 
 def prefill(params, batch, S_max: int, cfg: ModelConfig, *, use_kernels=False):
     """Run the prompt, return (last-position logits, decode cache). The
-    uniform stack's cache holds k/v as (L, ...) stacks in
-    ``kv_order_for(hd)``, padded to ``S_max`` positions."""
-    x, n_prefix = _embed_inputs(params, cfg, batch)
-    B, S = x.shape[0], x.shape[1]
-    dtype = dtype_of(cfg)
-    positions = jnp.arange(S)
+    cache holds k/v as (L, ...) stacks in ``kv_order_for(hd)``, padded to
+    ``S_max`` positions."""
+    x, _ = _embed_inputs(params, cfg, batch)
+    S = x.shape[1]
     h, kvs, _ = _forward(
-        params, cfg, x, positions, want_cache=True, use_kernels=use_kernels
+        params, cfg, x, jnp.arange(S), want_cache=True, use_kernels=use_kernels
     )
     h = _final_norm(params, cfg, h)
     logits = _logits(params, cfg, h[:, -1])
-
-    if cfg.local_global_ratio:
-        W = cfg.sliding_window
-        lkv, gkv = kvs["local"], kvs["global"]
-        # local layers keep only the trailing window (ring buffer)
-        take = min(W, S)
-        lk = lkv.k[..., S - take:, :, :]
-        lv = lkv.v[..., S - take:, :, :]
-        if take < W:
-            pad = [(0, 0)] * lk.ndim
-            pad[-3] = (0, W - take)
-            lk, lv = jnp.pad(lk, pad), jnp.pad(lv, pad)
-        ring_pos = jnp.where(
-            jnp.arange(W) < take, jnp.arange(W) + (S - take), -1
-        ).astype(jnp.int32)
-        # global layers get a full-length cache buffer
-        def grow(a):
-            pad = [(0, 0)] * a.ndim
-            pad[-3] = (0, S_max - S)
-            return jnp.pad(a, pad)
-        cache = {
-            "lk": lk, "lv": lv, "ring_pos": ring_pos,
-            "gk": grow(gkv.k), "gv": grow(gkv.v),
-            "pos": jnp.int32(S),
-        }
-    else:
-        seq = 1 + kv_order_for(cfg.hd).index(1)  # the stacks' sequence axis
-
-        def grow(a):
-            pad = [(0, 0)] * a.ndim
-            pad[seq] = (0, S_max - S)
-            return jnp.pad(a, pad)
-        cache = {"k": grow(kvs.k), "v": grow(kvs.v), "pos": jnp.int32(S)}
-    return logits, cache
+    k, v = pad_stacks(kvs, cfg.hd, S_max)
+    return logits, {"k": k, "v": v, "pos": jnp.int32(S)}
 
 
 def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
@@ -301,61 +221,24 @@ def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
     pos = cache["pos"]
     positions = pos[None]
 
-    if cfg.local_global_ratio:
-        W = cfg.sliding_window
-        g_theta = cfg.global_rope_theta or cfg.rope_theta
-        wp = jnp.mod(pos, W)
-        ring_pos = jax.lax.dynamic_update_slice(cache["ring_pos"], pos[None], (wp,))
+    # the cache stacks ride in the carry and are updated in place; a scan
+    # over them as xs/ys would slice and restack them whole every step
+    def body(carry, inp):
+        x, _, ks, vs = carry
+        lp, layer = inp
+        x, kv, a = _layer_apply(
+            lp, x, cfg, positions=positions, theta=cfg.rope_theta,
+            window=cfg.sliding_window, cache=KVCache(ks, vs),
+            cache_pos=pos, layer=layer, use_kernels=use_kernels,
+        )
+        return (x, a, kv.k, kv.v), None
 
-        def block(x, bp):
-            lps, lk, lv, gp, gk, gv = bp
-
-            def inner(xc, inp):
-                lp, k1, v1 = inp
-                xc, kv, _ = _layer_apply(
-                    lp, xc, cfg, positions=positions, theta=cfg.rope_theta,
-                    window=W, cache=KVCache(k1, v1), cache_pos=pos,
-                    cache_write_pos=wp, kv_positions=ring_pos,
-                    use_kernels=use_kernels,
-                )
-                return xc, kv
-
-            x, lkv = jax.lax.scan(inner, x, (lps, lk, lv))
-            x, gkv, _ = _layer_apply(
-                gp, x, cfg, positions=positions, theta=g_theta, window=None,
-                cache=KVCache(gk, gv), cache_pos=pos, use_kernels=use_kernels,
-            )
-            return x, (lkv, gkv)
-
-        with jax.named_scope("layers"):
-            x, (lkvs, gkvs) = jax.lax.scan(
-                block, x,
-                (params["local_layers"], cache["lk"], cache["lv"],
-                 params["global_layers"], cache["gk"], cache["gv"]),
-            )
-        new_cache = {
-            "lk": lkvs.k, "lv": lkvs.v, "ring_pos": ring_pos,
-            "gk": gkvs.k, "gv": gkvs.v, "pos": pos + 1,
-        }
-    else:
-        # the cache stacks ride in the carry and are updated in place; a scan
-        # over them as xs/ys would slice and restack them whole every step
-        def body(carry, inp):
-            x, _, ks, vs = carry
-            lp, layer = inp
-            x, kv, a = _layer_apply(
-                lp, x, cfg, positions=positions, theta=cfg.rope_theta,
-                window=cfg.sliding_window, cache=KVCache(ks, vs),
-                cache_pos=pos, layer=layer, use_kernels=use_kernels,
-            )
-            return (x, a, kv.k, kv.v), None
-
-        with jax.named_scope("layers"):
-            (x, _, k, v), _ = jax.lax.scan(
-                body, (x, 0.0, cache["k"], cache["v"]),
-                (params["layers"], jnp.arange(cfg.n_layers)),
-            )
-        new_cache = {"k": k, "v": v, "pos": pos + 1}
+    with jax.named_scope("layers"):
+        (x, _, k, v), _ = jax.lax.scan(
+            body, (x, 0.0, cache["k"], cache["v"]),
+            (params["layers"], jnp.arange(cfg.n_layers)),
+        )
+    new_cache = {"k": k, "v": v, "pos": pos + 1}
 
     h = _final_norm(params, cfg, x)
     logits = _logits(params, cfg, h[:, 0])
@@ -365,20 +248,7 @@ def decode_step(params, cache, batch, cfg: ModelConfig, *, use_kernels=False):
 def init_cache(cfg: ModelConfig, B: int, S_max: int):
     """An empty decode cache, laid out as ``prefill`` returns it."""
     dtype = dtype_of(cfg)
-    K, hd = cfg.n_kv_heads, cfg.hd
-    if cfg.local_global_ratio:
-        R = cfg.local_global_ratio
-        G = cfg.n_layers // (R + 1)
-        W = cfg.sliding_window
-        return {
-            "lk": jnp.zeros((G, R, B, W, K, hd), dtype),
-            "lv": jnp.zeros((G, R, B, W, K, hd), dtype),
-            "ring_pos": jnp.full((W,), -1, jnp.int32),
-            "gk": jnp.zeros((G, B, S_max, K, hd), dtype),
-            "gv": jnp.zeros((G, B, S_max, K, hd), dtype),
-            "pos": jnp.int32(0),
-        }
-    stack = kv_stack_shape(cfg.n_layers, B, S_max, K, hd)
+    stack = kv_stack_shape(cfg.n_layers, B, S_max, cfg.n_kv_heads, cfg.hd)
     return {
         "k": jnp.zeros(stack, dtype),
         "v": jnp.zeros(stack, dtype),

@@ -18,7 +18,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.attention import kv_order_for
+from ..models.attention import kv_axes
 
 MODEL = "model"
 
@@ -103,8 +103,6 @@ _PARAM_RULES: list[tuple[str, tuple]] = [
 # how many leading stacked-layer dims each top-level group carries
 _STACK_DIMS = {
     "layers": 1,
-    "local_layers": 2,
-    "global_layers": 1,
     "mamba_groups": 2,
     "mamba_tail": 1,
     "mamba_layers": 1,
@@ -198,56 +196,44 @@ def batch_shardings(mesh: Mesh, batch_like):
 
 
 def cache_shardings(mesh: Mesh, cache_like, cfg):
-    """Decode caches: batch over dp where divisible; KV sequence over "model"
-    (flash-decode style context parallelism — head-count agnostic); SSM/mLSTM
-    states shard heads or channels over "model"."""
+    """Decode caches, matched by the name of each top-level entry. Every
+    entry shards its batch over dp where that divides.
+
+    * ``k``/``v``, every family's (L, ...) KV stacks (``kv_axes``): the
+      sequence over "model" (flash-decode style context parallelism, head-count
+      agnostic); granite-H's heads instead.
+    * ``ck``/``cv``, whisper's cross-attention K/V (L, B, T, K, hd): T over
+      "model".
+    * Mamba2 states ``h``/``g_h``/``t_h`` (..., B, H, P, N): heads over
+      "model". Conv windows: granite-H's ``conv`` (L, W-1, B, Ch), Zamba2's
+      ``g_conv``/``t_conv`` (..., B, W-1, Ch).
+    * xLSTM states, the tuples ``s`` (nb, B, ...) and ``m`` (nb, nm, B, ...):
+      batch-local. Any model-axis sharding of the mLSTM matrix memory loses:
+      GSPMD cannot reshard between the layouts the decode einsums prefer and
+      replicates the whole cache every step (measured 113 -> 254 ms/step).
+    """
     dp = dp_axes(mesh)
-    # the transformer's uniform layer stack and granite-H's attention layers
-    # keep k/v as (L, ...) stacks in kv_order_for(hd), a permutation of
-    # (B, S, K, hd): axis a of that lies at 1 + order.index(a)
-    uniform_stack = cfg.family in ("dense", "moe", "vlm") and not cfg.local_global_ratio
-    at = [1 + i for i in (kv_order_for(cfg.hd).index(a) for a in range(4))]
+    kv = kv_axes(cfg.hd)
+    kv_model = kv.heads if cfg.layer_types else kv.seq
 
     def one(path, leaf):
-        name = _path_str(path)
-        shape = leaf.shape
-        spec: list = [None] * len(shape)
-        if leaf.ndim == 0:
-            return NamedSharding(mesh, P())
-        if cfg.layer_types and name in ("k", "v", "h", "conv"):
-            # granite-h: k/v shard batch over dp and heads over model, as do
-            # the states (L, B, H, P, N); conv windows (L, W-1, B, Ch)
-            if name == "conv":
-                spec[-2] = dp
-            elif name == "h":
-                spec[-4], spec[-3] = dp, MODEL
-            else:
-                spec[at[0]], spec[at[2]] = dp, MODEL
-            return NamedSharding(mesh, _sanitize(mesh, spec, shape))
-        if uniform_stack and name in ("k", "v"):
-            spec[at[0]], spec[at[1]] = dp, MODEL
-            return NamedSharding(mesh, _sanitize(mesh, spec, shape))
-        # mlstm matrix memory (..., B, H, dh, dh): BATCH-LOCAL (dp only).
-        # Any model-axis sharding here loses: GSPMD cannot reshard between
-        # the layouts the decode einsums prefer and replicates the whole
-        # cache every step ("involuntary full rematerialization") — measured
-        # 113 -> 254 ms/step before this rule. Recurrent decode is
-        # embarrassingly parallel over batch; keep it that way.
-        if leaf.ndim >= 5 and leaf.shape[-1] == leaf.shape[-2]:
-            spec[-4] = dp
-        # KV-style caches: (..., B, S, K, hd)
-        elif any(k in name for k in ("k", "v", "sk", "sv", "gk", "gv", "ck", "cv")) \
-                and leaf.ndim >= 4 and "ring" not in name and "conv" not in name:
-            spec[-4] = dp
-            spec[-3] = MODEL
-        elif "conv" in name:                       # (..., B, W-1, Ch)
+        name = path[0].key
+        spec: list = [None] * leaf.ndim
+        if name in ("k", "v"):
+            spec[kv.batch], spec[kv_model] = dp, MODEL
+        elif name in ("ck", "cv"):
+            spec[1], spec[2] = dp, MODEL
+        elif name in ("h", "g_h", "t_h"):
+            spec[-4], spec[-3] = dp, MODEL
+        elif name == "conv":
+            spec[2] = dp
+        elif name in ("g_conv", "t_conv"):
             spec[-3] = dp
-        elif name.endswith("h") and leaf.ndim >= 4:  # ssm state (..., B, H, P, N)
-            spec[-4] = dp
-            spec[-3] = MODEL
-        elif leaf.ndim >= 3:                        # n/m/c/h recurrent states
-            spec[-3] = dp
-        return NamedSharding(mesh, _sanitize(mesh, spec, shape))
+        elif name == "s":
+            spec[1] = dp
+        elif name == "m":
+            spec[2] = dp
+        return NamedSharding(mesh, _sanitize(mesh, spec, leaf.shape))
 
     return jax.tree_util.tree_map_with_path(one, cache_like)
 

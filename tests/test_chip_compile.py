@@ -33,6 +33,7 @@ from repro.runtime import RuntimeConfig, jit_decode_step, jit_prefill
 PHI4 = get_config("phi4-mini-3.8b")
 GRANITE_H = get_config("granite-4.0-h-micro")
 ZAMBA2 = get_config("zamba2-7b")
+WHISPER = get_config("whisper-tiny")
 BF16 = jnp.bfloat16
 
 
@@ -92,12 +93,20 @@ def test_ssd_intra_chunk_compiles_zamba2(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("B, S_max", [(8, 256), (2, 256)])
-def test_decode_step_keeps_cache_in_place(one_chip, B, S_max):
-    """phi4-mini widths, 2 layers, a batch of 8 and of 2 (phi4-longdoc's):
-    the decode step needs no temporary as large as one layer's K slice, and
-    copies no whole cache stack."""
-    cfg = dataclasses.replace(PHI4, n_layers=2)
+@pytest.mark.parametrize("cfg, B, S_max", [
+    pytest.param(dataclasses.replace(PHI4, n_layers=2), 8, 256, id="8-256"),
+    pytest.param(dataclasses.replace(PHI4, n_layers=2), 2, 256, id="2-256"),
+    # two groups of 6 mamba layers and the shared attention block: a stack of
+    # one entry would not show a restacking copy
+    pytest.param(dataclasses.replace(ZAMBA2, n_layers=12), 8, 256, id="zamba2-7b"),
+    pytest.param(WHISPER, 8, 256, id="whisper-tiny"),
+])
+def test_decode_step_keeps_cache_in_place(one_chip, cfg, B, S_max):
+    """At published widths (phi4-mini cut to 2 layers at a batch of 8 and of
+    2, phi4-longdoc's; Zamba2 cut to two groups; whisper-tiny) the decode
+    step copies no whole KV stack and needs no temporary as large as one
+    layer's K slice. Zamba2's mamba states still pass through its scans as
+    inputs and outputs, so its temporaries are not bounded."""
     model = build_model(cfg)
     params_like = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     cache_like = jax.eval_shape(lambda: model.init_cache(B, S_max))
@@ -108,7 +117,8 @@ def test_decode_step_keeps_cache_in_place(one_chip, B, S_max):
                                cache_like, tok_like)
     compiled = step.lower(params_like, cache_like, tok_like).compile()
     layer_k_bytes = S_max * cfg.n_kv_heads * B * cfg.hd * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < layer_k_bytes
+    if cfg.family != "hybrid":
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_k_bytes
     stack = "bf16[" + ",".join(map(str, cache_like["k"].shape)) + "]"
     # copy ops and copy fusions of a whole stack: a scan over the stacks as
     # xs and ys makes four a step, restacking the ys and copying them out;

@@ -88,10 +88,10 @@ def test_decode_step_sharded_cache():
 
 
 def test_cache_shardings_follow_the_stack_layout():
-    """The uniform stack's cache shards batch over data and sequence over
+    """Every family's k/v stacks shard batch over data and sequence over
     model wherever kv_order_for(hd) puts them: (L, B, K, hd, S) for the smoke
-    configs' heads of 32, (L, B, K, S, hd) for heads of 128; gemma3's
-    (..., B, S, K, hd) caches keep theirs."""
+    configs' heads of 32, (L, B, K, S, hd) for heads of 128. Zamba2's stacks
+    hold one entry per invocation site of its shared block."""
     _run("""
         import dataclasses
         import jax
@@ -107,8 +107,9 @@ def test_cache_shardings_follow_the_stack_layout():
             (get_smoke("qwen3-14b"), {"k": P(None, "data", None, None, "model")}),
             (get_smoke("internvl2-2b"), {"v": P(None, "data", None, None, "model")}),
             (wide, {"k": P(None, "data", None, "model", None)}),
-            (get_smoke("gemma3-12b"), {"gk": P(None, "data", "model", None, None),
-                                       "lk": P(None, None, "data", "model", None, None)}),
+            (get_smoke("zamba2-7b"), {"k": P(None, "data", None, None, "model")}),
+            (get_smoke("whisper-tiny"), {"v": P(None, "data", None, None, "model"),
+                                         "ck": P(None, "data", "model", None, None)}),
         ]:
             model = build_model(cfg)
             cache = jax.eval_shape(lambda: model.init_cache(8, 64))

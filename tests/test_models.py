@@ -15,12 +15,13 @@ from repro.models import transformer
 from repro.models.attention import (
     HEAD_ROWS,
     SEQ_MINOR,
-    KVCache,
     blockwise_sdpa,
+    kv_axes,
     kv_order_for,
     kv_stack_shape,
     sdpa,
 )
+from repro.models.layers import apply_rope, dense, rmsnorm, rope_tables, swiglu
 from repro.runtime import (
     RuntimeConfig,
     make_train_state,
@@ -74,9 +75,20 @@ def test_smoke_train_step_improves(arch):
         assert bool(jnp.all(jnp.isfinite(leaf.astype(jnp.float32))))
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+# a dense smoke config whose every layer attends within a window of 8, shorter
+# than the prompt: the window mask over the carried stacks
+WINDOWED = "phi4-mini-3.8b+window8"
+
+
+def _smoke(arch):
+    if arch == WINDOWED:
+        return dataclasses.replace(get_smoke("phi4-mini-3.8b"), sliding_window=8)
+    return get_smoke(arch)
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS + (WINDOWED,))
 def test_decode_matches_full_forward(arch):
-    cfg = get_smoke(arch)
+    cfg = _smoke(arch)
     if cfg.family == "moe":
         cfg = dataclasses.replace(cfg, moe_capacity_factor=8.0)  # no drops
     model = build_model(cfg)
@@ -94,14 +106,13 @@ def test_decode_matches_full_forward(arch):
     assert err / scale < 2e-2, (arch, err, scale)
 
 
-# one (L, ...) stack per k and v in kv_order_for(hd), carried through the
-# decode scan
-UNIFORM_STACK = [a for a in ARCH_IDS
-                 if get_smoke(a).family in ("dense", "moe", "vlm")
-                 and not get_smoke(a).local_global_ratio]
+# every family with attention keeps one (L, ...) stack per k and v in
+# kv_order_for(hd), carried through the decode scan: L layers, Zamba2's
+# invocation sites of its shared block, granite-H's attention layers
+KV_STACKS = [a for a in ARCH_IDS if get_smoke(a).family != "ssm"]
 
 
-@pytest.mark.parametrize("arch", UNIFORM_STACK)
+@pytest.mark.parametrize("arch", KV_STACKS)
 def test_decode_cache_matches_prefill(arch):
     """Four decode steps write the rows a prefill of the whole sequence
     writes, in the stacks' layout, and leave the rows after them zero."""
@@ -125,7 +136,7 @@ def test_decode_cache_matches_prefill(arch):
         assert cache[name].shape == model.init_cache(B, S_max)[name].shape
         got = np.asarray(cache[name]).transpose(back)
         want = np.asarray(full[name]).transpose(back)
-        assert got.shape == (cfg.n_layers, B, S_max, cfg.n_kv_heads, cfg.hd)
+        assert got.shape == (cache[name].shape[0], B, S_max, cfg.n_kv_heads, cfg.hd)
         err = np.max(np.abs(got[:, :, :n] - want[:, :, :n]))
         assert err / np.max(np.abs(want[:, :, :n])) < 2e-2, (arch, name, err)
         assert not np.any(got[:, :, n:]), (arch, name)
@@ -145,6 +156,25 @@ def test_kv_order_follows_the_head_width():
     assert kv_order_for(64) == kv_order_for(32) == SEQ_MINOR
     assert kv_stack_shape(32, 24, 1024, 8, 128) == (32, 24, 8, 1024, 128)
     assert kv_stack_shape(4, 24, 4608, 8, 64) == (4, 24, 8, 64, 4608)
+    assert kv_axes(128) == (1, 3, 2)
+    assert kv_axes(64) == (1, 4, 2)
+
+
+def _plain_decode_layer(lp, x, cfg, k, v, pos):
+    """One dense layer's decode step with its cache as (B, S, K, hd) arrays,
+    written by dynamic_update_slice and read by ``sdpa``."""
+    B, H, K, hd = x.shape[0], cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    xn = rmsnorm(lp["ln1"], x, cfg.norm_eps)
+    q = dense(lp["attn"]["wq"], xn).reshape(B, 1, H, hd)
+    kn = dense(lp["attn"]["wk"], xn).reshape(B, 1, K, hd)
+    vn = dense(lp["attn"]["wv"], xn).reshape(B, 1, K, hd)
+    cos, sin = rope_tables(pos[None], hd, cfg.rope_theta)
+    q, kn = apply_rope(q, cos, sin), apply_rope(kn, cos, sin)
+    k = jax.lax.dynamic_update_slice(k, kn, (0, pos, 0, 0))
+    v = jax.lax.dynamic_update_slice(v, vn, (0, pos, 0, 0))
+    out = sdpa(q, k, v, q_offset=pos, kv_len=pos + 1)
+    x = x + dense(lp["attn"]["wo"], out.reshape(B, 1, H * hd))
+    return x + swiglu(lp["mlp"], rmsnorm(lp["ln2"], x, cfg.norm_eps)), k, v
 
 
 def test_head_rows_decode_matches_plain_sdpa():
@@ -170,10 +200,7 @@ def test_head_rows_decode_matches_plain_sdpa():
         x = transformer.embed(params["embed"], tok[:, None])
         for i in range(cfg.n_layers):
             lp = jax.tree.map(lambda a: a[i], params["layers"])
-            x, kv, _ = transformer._layer_apply(
-                lp, x, cfg, positions=pos[None], theta=cfg.rope_theta, window=None,
-                cache=KVCache(ks[i], vs[i]), cache_pos=pos)
-            ks[i], vs[i] = kv.k, kv.v
+            x, ks[i], vs[i] = _plain_decode_layer(lp, x, cfg, ks[i], vs[i], pos)
         want = transformer._logits(params, cfg, transformer._final_norm(params, cfg, x)[:, 0])
         np.testing.assert_allclose(np.asarray(logits), np.asarray(want), rtol=1e-5, atol=1e-5)
 
@@ -200,8 +227,7 @@ def test_decode_kernel_path_matches_jnp(hd):
     np.testing.assert_allclose(np.asarray(ker["k"]), np.asarray(ref["k"]), rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "zamba2-7b", "gemma3-12b",
-                                  "granite-4.0-h-micro"])
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "zamba2-7b", "granite-4.0-h-micro"])
 def test_kernel_path_matches_reference(arch):
     cfg = get_smoke(arch)
     model = build_model(cfg)
@@ -220,15 +246,6 @@ def test_output_logits_shape_padded_vocab():
     logits, cache = model.prefill(params, batch, S + cfg.n_patches + 2)
     assert logits.shape == (B, cfg.padded_vocab)
     assert cfg.padded_vocab % 256 == 0
-
-
-def test_gemma3_local_cache_is_windowed():
-    cfg = get_smoke("gemma3-12b")
-    model = build_model(cfg)
-    cache = model.init_cache(B, 128)
-    W = cfg.sliding_window
-    assert cache["lk"].shape[-3] == W        # ring buffer, not full length
-    assert cache["gk"].shape[-3] == 128      # global layers keep full cache
 
 
 def test_moe_capacity_drops_tokens():

@@ -41,8 +41,8 @@ class TestParamRules:
             P(None, "model", None, None)
 
     def test_stacked_dims_padded(self):
-        # gemma3 local layers have two leading stack dims
-        assert param_spec(MESH, "local_layers/attn/wq/w", (8, 5, 3840, 4096)) == \
+        # zamba2's grouped mamba layers have two leading stack dims
+        assert param_spec(MESH, "mamba_groups/mamba/in_proj/w", (13, 6, 3584, 14576)) == \
             P(None, None, None, "model")
 
     def test_norms_replicated(self):
